@@ -410,7 +410,6 @@ def bench_collective_rows(*, smoke: bool) -> List[dict]:
 def _collective_rows_inner(*, smoke: bool) -> List[dict]:
     import numpy as np
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.sharding.collectives import dense_psum, sparse_psum
@@ -448,9 +447,9 @@ def _collective_rows_inner(*, smoke: bool) -> List[dict]:
 
             for variant, body in (("dense_psum", body_dense),
                                   ("bitmap", body_bitmap)):
-                fn = jax.jit(shard_map(
+                fn = jax.jit(jax.shard_map(
                     body, mesh=mesh, in_specs=(spec_in, spec_in),
-                    out_specs=P(), check_rep=False))
+                    out_specs=P(), check_vma=False))
                 rows.append({
                     "table": "collective",
                     "mesh": "x".join(map(str, shape)),

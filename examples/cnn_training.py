@@ -16,6 +16,7 @@ import jax.numpy as jnp
 
 from repro.core.policy import IN_OUT_WR
 from repro.data.pipeline import image_batch
+from repro.launch.cache import use_compile_cache
 from repro.models.cnn import NETWORKS, build_cnn
 
 
@@ -33,6 +34,7 @@ def main() -> None:
                          "on-device prefix-sum compaction (default) or the "
                          "argsort reference")
     args = ap.parse_args()
+    use_compile_cache()
 
     model = build_cnn(args.net, image_size=args.image_size, width=args.width,
                       num_classes=100)

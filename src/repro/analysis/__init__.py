@@ -13,7 +13,6 @@ from .jaxpr_audit import WORKLOADS, audit_fn, audit_jaxpr, audit_workloads
 from .kernel_sanitizer import (
     run_compact_grouped,
     run_predicated_grouped,
-    run_queue_builder,
     sanitize_all,
 )
 from .lint import lint_paths, lint_source
@@ -30,7 +29,6 @@ __all__ = [
     "lint_source",
     "run_compact_grouped",
     "run_predicated_grouped",
-    "run_queue_builder",
     "sanitize_all",
     "to_csv",
     "to_json",

@@ -4,8 +4,8 @@
 write that lands twice, a store that strays outside the padded grid, or a
 stale accumulator read — those all still produce *some* value.  This
 module re-executes the kernel *functions* (the plain Python bodies in
-``kernels/masked_matmul.py`` / ``kernels/queue_builder.py``) over numpy
-shadow memory with every ref access instrumented:
+``kernels/masked_matmul.py``) over numpy shadow memory with every ref
+access instrumented:
 
   ACC_READ_BEFORE_WRITE  a VMEM accumulator is read (``+=`` reads!) in an
                          output tile's K-chain before that chain zeroed it
@@ -16,14 +16,6 @@ shadow memory with every ref access instrumented:
   STORE_OOB              a store outside the ref's padded block window
                          (numpy would silently wrap negative indices; the
                          shadow ref bounds-checks *before* storing).
-  QUEUE_WRITE_OOB        the queue builder stores a slot index beyond the
-                         dump slot (``> capacity``) — overflow corrupting
-                         memory past the queue.
-  DUMP_SLOT_LEAK         live queue slots not written exactly once, or dead
-                         slots written at all (post-init) — compaction
-                         leaking through the dump-slot quarantine.
-  QUEUE_ORDER            the final queue content (or emitted live count)
-                         disagrees with ``core.workredist.static_queue_order``.
 
 The kernel bodies only reach ``pl`` / ``jnp`` / ``jax`` through module
 globals, so a shadow run swaps those globals for shims for the duration of
@@ -40,6 +32,7 @@ import importlib
 import sys
 from typing import Callable, List, Optional, Tuple
 
+import jax
 import numpy as np
 
 from .report import Violation
@@ -54,20 +47,14 @@ class ShadowRef:
 
     ``epochal`` refs model the VMEM accumulator: the driver bumps ``epoch``
     when a new output tile's K-chain begins, and a read while
-    ``last_write_epoch < epoch`` is a stale read.  ``split_bulk`` refs (the
-    queue outputs) count whole-window initializations separately from
-    per-slot stores, so the dump-slot accounting can ignore the one
-    sanctioned ``ref[...] = zeros`` init.
+    ``last_write_epoch < epoch`` is a stale read.
     """
 
-    def __init__(self, shape, dtype, name: str, *,
-                 epochal: bool = False, split_bulk: bool = False):
+    def __init__(self, shape, dtype, name: str, *, epochal: bool = False):
         self.data = np.zeros(shape, dtype)
         self.writes = np.zeros(shape, np.int64)
-        self.bulk_writes = 0
         self.name = name
         self.epochal = epochal
-        self.split_bulk = split_bulk
         self.epoch = 0
         self.last_write_epoch = -1
 
@@ -137,14 +124,9 @@ class RefView:
                 f"{self.shape} block window at {self.san.step_label()}")
             return
         target = self.shadow.data[self.window]
-        probe = np.zeros_like(target, dtype=bool)
-        probe[sel] = True
-        if self.shadow.split_bulk and probe.all():
-            self.shadow.bulk_writes += 1
-        else:
-            counts = self.shadow.writes[self.window]
-            counts[sel] += 1
-            self.shadow.writes[self.window] = counts
+        counts = self.shadow.writes[self.window]
+        counts[sel] += 1
+        self.shadow.writes[self.window] = counts
         target[sel] = val
         self.shadow.data[self.window] = target
         self.shadow.last_write_epoch = self.shadow.epoch
@@ -172,10 +154,6 @@ class _PlShim:
             return fn
         return deco
 
-    @staticmethod
-    def dslice(start, size):
-        return slice(int(start), int(start) + int(size))
-
 
 class _JnpShim:
     """numpy plus the handful of jnp-isms the kernels use on refs."""
@@ -191,7 +169,7 @@ class _JnpShim:
         return np.zeros_like(x)
 
     @staticmethod
-    def dot(a, b, preferred_element_type=np.float32):
+    def dot(a, b, preferred_element_type=np.float32, precision=None):
         return np.dot(np.asarray(a, np.float32), np.asarray(b, np.float32)) \
             .astype(preferred_element_type)
 
@@ -200,17 +178,23 @@ class _JnpShim:
 
 
 class _LaxShim:
+    Precision = jax.lax.Precision
+
     @staticmethod
-    def fori_loop(lo, hi, body, init):
-        # Concrete Python loop: indices stay ints, so the shadow write log
-        # sees real slot numbers (a traced fori_loop would hide them).
-        carry = init
-        for e in range(int(lo), int(hi)):
-            carry = body(e, carry)
-        return carry
+    def broadcasted_iota(dtype, shape, dimension):
+        return np.indices(shape, dtype)[dimension]
+
+    @staticmethod
+    def dot_general(a, b, dimension_numbers, preferred_element_type=None):
+        (ca, cb), batch = dimension_numbers
+        assert batch == ((), ()), batch     # the kernels use no batch dims
+        return np.tensordot(np.asarray(a, np.float32),
+                            np.asarray(b, np.float32), (ca, cb))
 
 
 class _JaxShim:
+    config = jax.config
+
     def __init__(self):
         self.lax = _LaxShim()
 
@@ -276,6 +260,12 @@ def _tile3(gi, i, j, bm, bn):
             slice(j * bn, (j + 1) * bn))
 
 
+def _bits_tile(gi, i, j):
+    """Window of tile (gi, i, j) in the (G, Mb, Nb, cp, rp) bits layout."""
+    return (slice(gi, gi + 1), slice(i, i + 1), slice(j, j + 1),
+            slice(None), slice(None))
+
+
 # ---------------------------------------------------------------------------
 # Drivers — one per kernel family; geometry mirrored from the wrappers
 # ---------------------------------------------------------------------------
@@ -311,11 +301,12 @@ def run_predicated_grouped(
         else input_ref(np.asarray(epilogue_mult, np.float32), "mult_ref")
     bits = None
     if emit_gran is not None:
-        er, ec = emit_gran
-        bits = ShadowRef((g, m // er, n // ec), np.int32, "bits_ref")
-    om = np.asarray(out_mask, np.int32)
-    am = np.asarray(a_mask, np.int32)
-    bmsk = np.asarray(b_mask, np.int32)
+        cp, rp = importlib.import_module(
+            "repro.kernels.bits").bits_tile_shape(bm, bn, *emit_gran)
+        bits = ShadowRef((g, ni, nj, cp, rp), np.int32, "bits_ref")
+    om = np.asarray(out_mask, np.int32).reshape(-1)   # flat, as prefetched
+    am = np.asarray(a_mask, np.int32).reshape(-1)
+    bmsk = np.asarray(b_mask, np.int32).reshape(-1)
 
     def step(point):
         gi, i, j, kk = point
@@ -331,9 +322,7 @@ def run_predicated_grouped(
             refs.append(RefView(mult_s, _tile3(gi, i, j, bm, bn), san))
         refs.append(RefView(o, _tile3(gi, i, j, bm, bn), san))
         if bits is not None:
-            er, ec = emit_gran
-            refs.append(RefView(
-                bits, _tile3(gi, i, j, bm // er, bn // ec), san))
+            refs.append(RefView(bits, _bits_tile(gi, i, j), san))
         refs.append(RefView(acc, (slice(None), slice(None)), san))
         kernel_fn(om, am, bmsk, *refs)
 
@@ -342,9 +331,7 @@ def run_predicated_grouped(
              for gi in range(g) for i in range(ni) for j in range(nj)]
     _check_single_writeback(san, o, tiles)
     if bits is not None:
-        er, ec = emit_gran
-        btiles = [(f"bits(g={gi},i={i},j={j})",
-                   _tile3(gi, i, j, bm // er, bn // ec))
+        btiles = [(f"bits(g={gi},i={i},j={j})", _bits_tile(gi, i, j))
                   for gi in range(g) for i in range(ni) for j in range(nj)]
         _check_single_writeback(san, bits, btiles)
     return san.violations, o.data
@@ -363,11 +350,11 @@ def run_compact_grouped(
 ):
     """Shadow-run the grouped compacted kernel over grid (S, Kb)."""
     mmk = importlib.import_module("repro.kernels.masked_matmul")
+    g, m, k = a.shape
+    ni, nj, nk = m // bm, b.shape[2] // bn, k // bk
     if kernel_fn is None:
-        kernel_fn = mmk.gmm_compact_kernel_variant(epilogue_mult is not None,
-                                                   emit_gran)
-    k = a.shape[2]
-    nk = k // bk
+        kernel_fn = mmk.gmm_compact_kernel_variant(
+            epilogue_mult is not None, emit_gran, tiles=(ni, nj))
     gg = np.asarray(gg, np.int32)
     ii = np.asarray(ii, np.int32)
     jj = np.asarray(jj, np.int32)
@@ -382,11 +369,12 @@ def run_compact_grouped(
         else input_ref(np.asarray(epilogue_mult, np.float32), "mult_ref")
     bits = None
     if emit_gran is not None:
-        er, ec = emit_gran
-        bits = ShadowRef((s_cap, bm // er, bn // ec), np.int32, "bits_ref")
+        cp, rp = importlib.import_module(
+            "repro.kernels.bits").bits_tile_shape(bm, bn, *emit_gran)
+        bits = ShadowRef((s_cap, cp, rp), np.int32, "bits_ref")
     na = np.asarray(n_active, np.int32)
-    am = np.asarray(a_mask, np.int32)
-    bmsk = np.asarray(b_mask, np.int32)
+    am = np.asarray(a_mask, np.int32).reshape(-1)   # flat, as prefetched
+    bmsk = np.asarray(b_mask, np.int32).reshape(-1)
 
     def step(point):
         s, kk = point
@@ -421,91 +409,13 @@ def run_compact_grouped(
     return san.violations, o.data
 
 
-def run_queue_builder(
-    bitmap: np.ndarray,                      # (Mb, Nb)
-    *, capacity: int, launch_block: int = 8,
-    kernel_fn: Optional[Callable] = None,
-    workload: str = "",
-):
-    """Shadow-run the prefix-sum queue builder over grid (T // lb,)."""
-    from repro.core.workredist import static_queue_order
-    qbk = importlib.import_module("repro.kernels.queue_builder")
-    kernel_fn = kernel_fn or qbk._queue_builder_kernel
-    mb, nb = np.asarray(bitmap).shape
-    t = mb * nb
-    lb = min(launch_block, t)
-    tp = (t + lb - 1) // lb * lb
-    flat = np.asarray(bitmap, np.int32).reshape(-1)
-    if tp != t:
-        flat = np.pad(flat, (0, tp - t))
-    blocks_s = input_ref(flat.reshape(tp // lb, lb), "bm_ref")
-
-    san = _Sanitizer(kernel_fn, workload)
-    ii = ShadowRef((capacity + 1, 1), np.int32, "ii_ref", split_bulk=True)
-    jj = ShadowRef((capacity + 1, 1), np.int32, "jj_ref", split_bulk=True)
-    cnt = ShadowRef((1, 1), np.int32, "cnt_ref")
-    carry = ShadowRef((1,), np.int32, "carry_ref")
-
-    def full(s):
-        return tuple(slice(None) for _ in s.data.shape)
-
-    def step(point):
-        (b,) = point
-        kernel_fn(RefView(blocks_s, (slice(b, b + 1), slice(None)), san),
-                  RefView(ii, full(ii), san), RefView(jj, full(jj), san),
-                  RefView(cnt, full(cnt), san),
-                  RefView(carry, full(carry), san),
-                  cap=capacity, nj=nb, lb=lb)
-
-    san.run((tp // lb,), step)
-
-    # Name the queue-specific failure: a store past the dump slot.
-    for i, v in enumerate(list(san.violations)):
-        if v.code == "STORE_OOB" and ("ii_ref" in v.message
-                                      or "jj_ref" in v.message):
-            san.violations[i] = Violation(
-                "kernel", "QUEUE_WRITE_OOB", v.where,
-                v.message + " — queue slot beyond the dump slot", v.workload)
-
-    ref_ii, ref_jj, n_live = static_queue_order(np.asarray(bitmap), capacity)
-    live = min(int(n_live), capacity)
-
-    # Dump-slot quarantine: live slots stored exactly once (the b==0
-    # whole-window init is a bulk write, counted separately), dead slots
-    # untouched; everything else must have landed in the dump row.
-    for name, ref in (("ii", ii), ("jj", jj)):
-        w = ref.writes[:capacity, 0]
-        if (w[:live] != 1).any():
-            bad = int(np.flatnonzero(w[:live] != 1)[0])
-            san.report("DUMP_SLOT_LEAK",
-                       f"live {name} slot {bad} stored {int(w[bad])} times "
-                       f"(contract: exactly once)")
-        if live < capacity and (w[live:] != 0).any():
-            bad = live + int(np.flatnonzero(w[live:] != 0)[0])
-            san.report("DUMP_SLOT_LEAK",
-                       f"dead {name} slot {bad} stored post-init "
-                       f"(dead/overflow stores belong in the dump slot)")
-
-    got_ii, got_jj = ii.data[:capacity, 0], jj.data[:capacity, 0]
-    if not (np.array_equal(got_ii, ref_ii)
-            and np.array_equal(got_jj, ref_jj)):
-        san.report("QUEUE_ORDER",
-                   "final queue content differs from the WDU reference "
-                   "order (core.workredist.static_queue_order)")
-    if int(cnt.data[0, 0]) != int(n_live):
-        san.report("QUEUE_ORDER",
-                   f"emitted n_live={int(cnt.data[0, 0])} != true set-bit "
-                   f"count {int(n_live)}")
-    return san.violations, (got_ii, got_jj, int(cnt.data[0, 0]))
-
-
 # ---------------------------------------------------------------------------
 # Standard sweep — the kernel half of the zero-violation gate
 # ---------------------------------------------------------------------------
 
 def sanitize_all() -> List[Violation]:
     """Shadow-run every launched kernel family on representative sparse
-    geometries (half-dead masks, empty, full, and overflowing queues)."""
+    geometries (half-dead masks, every epilogue combination)."""
     from repro.core.workredist import static_queue_order
     out: List[Violation] = []
     r = np.random.RandomState(0)
@@ -560,15 +470,4 @@ def sanitize_all() -> List[Violation]:
                                 workload="compact:epilogue+emit")
     out += vs
 
-    for label, bmp, cap in [
-        ("queue:half", (r.rand(4, 6) > 0.5).astype(np.int32), 24),
-        ("queue:empty", np.zeros((3, 5), np.int32), 15),
-        ("queue:full", np.ones((4, 4), np.int32), 16),
-        ("queue:overflow", np.ones((4, 4), np.int32), 5),
-        ("queue:ragged", (np.arange(7 * 3).reshape(7, 3) % 2)
-         .astype(np.int32), 11),
-    ]:
-        vs, _ = run_queue_builder(bmp, capacity=cap, launch_block=4,
-                                  workload=label)
-        out += vs
     return out

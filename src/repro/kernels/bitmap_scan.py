@@ -11,10 +11,10 @@ two jobs with no producing op to fuse into: the OPT-IN entry scan of raw
 signed model inputs (``SparsityPolicy.scan_signed_inputs``) and the
 numerical reference that emit-epilogue tests compare against.
 
-Same granularity/launch-slab decoupling as relu_encode: one grid step
-covers an (lr, lc) slab and reduces it with a single reshape-max, so the
-per-row granularities the conv path needs stay cheap to launch.  Signed
-data ⇒ the liveness predicate is ``|x| > 0``, not ``x > 0``.
+Same granularity/launch-slab decoupling and the same indicator-matmul
+reduction (``bits.any_nonzero_t``) as relu_encode, so the per-row
+granularities the conv path needs stay cheap to launch.  Signed data ⇒ the
+liveness predicate is ``x != 0``, not ``x > 0``.
 """
 from __future__ import annotations
 
@@ -24,17 +24,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from .bits import any_nonzero_t, bits_tile_shape, untile_bits
 
 
 def _bitmap_scan_kernel(x_ref, bm_ref, *, gr: int, gc: int):
-    x = x_ref[...].astype(jnp.float32)
-    lr, lc = x.shape
-    xb = jnp.abs(x).reshape(lr // gr, gr, lc // gc, gc)
-    bm_ref[...] = (jnp.max(xb, axis=(1, 3)) > 0).astype(jnp.int32)
+    bm_ref[0, 0] = any_nonzero_t(x_ref[...], gr, gc)
 
 
 def bitmap_scan_kernel(
@@ -49,7 +43,7 @@ def bitmap_scan_kernel(
     """Returns the (M//bm, N//bn) int32 any-nonzero bitmap of signed ``x``.
 
     (bm, bn) is the BITMAP granularity; (lr, lc) the launch tile (defaults:
-    whole array — the ops wrapper picks ~8-row slabs).
+    whole array — the ops wrapper sizes the slabs as for ``relu_encode``).
     """
     m, n = x.shape
     lr = lr or m
@@ -57,13 +51,13 @@ def bitmap_scan_kernel(
     assert m % lr == 0 and n % lc == 0, (x.shape, lr, lc)
     assert lr % bm == 0 and lc % bn == 0, (lr, lc, bm, bn)
     ni, nj = m // lr, n // lc
-    fr, fc = lr // bm, lc // bn
+    cp, rp = bits_tile_shape(lr, lc, bm, bn)
     fn = pl.pallas_call(
         functools.partial(_bitmap_scan_kernel, gr=bm, gc=bn),
         grid=(ni, nj),
         in_specs=[pl.BlockSpec((lr, lc), lambda i, j: (i, j))],
-        out_specs=pl.BlockSpec((fr, fc), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m // bm, n // bn), jnp.int32),
+        out_specs=pl.BlockSpec((1, 1, cp, rp), lambda i, j: (i, j, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((ni, nj, cp, rp), jnp.int32),
         interpret=interpret,
     )
-    return fn(x)
+    return untile_bits(fn(x), lr // bm, lc // bn)

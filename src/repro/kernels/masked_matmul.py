@@ -43,17 +43,28 @@ them, the same role the argsort queue builder plays for the prefix-sum one.
 """
 from __future__ import annotations
 
-import functools
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU-specific helpers; present in jax>=0.4 under .tpu
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from .bits import any_nonzero_t, bits_tile_shape, untile_bits
+
+
+def _mxu_dot(a, b):
+    """The kernels' MXU product, accumulated in f32.  Its precision follows
+    ``jax.default_matmul_precision``: Mosaic's default (one bf16 pass, as
+    XLA's default) where the setting is unset, "default" or "bfloat16", and
+    full f32 for any other value, since Mosaic lowers only those two and a
+    kernel must not compute below the precision asked for.  Pallas ignores
+    that setting unless it is passed on, so a reference computed at HIGHEST
+    would otherwise face default-precision kernels."""
+    lowest = jax.config.jax_default_matmul_precision in (
+        None, "default", "bfloat16")
+    return jnp.dot(a, b, preferred_element_type=jnp.float32,
+                   precision=None if lowest else jax.lax.Precision.HIGHEST)
 
 
 # ---------------------------------------------------------------------------
@@ -82,9 +93,7 @@ def _mm_kernel(out_m_ref, a_m_ref, b_m_ref, a_ref, b_ref, o_ref, acc_ref):
 
     @pl.when(active)
     def _issue_mxu():
-        acc_ref[...] += jnp.dot(
-            a_ref[...], b_ref[...], preferred_element_type=jnp.float32
-        )
+        acc_ref[...] += _mxu_dot(a_ref[...], b_ref[...])
 
     @pl.when(k == nk - 1)
     def _write():
@@ -113,9 +122,7 @@ def _mm_epilogue_kernel(out_m_ref, a_m_ref, b_m_ref, a_ref, b_ref, mult_ref,
 
     @pl.when(active)
     def _issue_mxu():
-        acc_ref[...] += jnp.dot(
-            a_ref[...], b_ref[...], preferred_element_type=jnp.float32
-        )
+        acc_ref[...] += _mxu_dot(a_ref[...], b_ref[...])
 
     @pl.when(k == nk - 1)
     def _write():
@@ -198,16 +205,13 @@ def _apply_epilogue(acc, mult_tile, o_dtype, emit_gran):
          so the emitted bits describe exactly the values written back.
 
     ``acc`` may be (bm, bn) (predicated family) or (1, bm, bn) (compact
-    family); the returned bits are always the 2-D (bm//er, bn//ec) tile.
+    family); the returned bits are the transposed, padded
+    ``bits_tile_shape(bm, bn, er, ec)`` tile of ``any_nonzero_t``.
     """
     out = acc if mult_tile is None else acc * mult_tile
     bits = None
     if emit_gran is not None:
-        er, ec = emit_gran
-        v = out if out.ndim == 2 else out[0]
-        r, c = v.shape
-        vb = jnp.abs(v).reshape(r // er, er, c // ec, ec)
-        bits = (jnp.max(vb, axis=(1, 3)) > 0).astype(jnp.int32)
+        bits = any_nonzero_t(out if out.ndim == 2 else out[0], *emit_gran)
     return out.astype(o_dtype), bits
 
 
@@ -225,6 +229,13 @@ def _epilogue_refs(refs, has_mult, emit_gran):
 # Grouped predicated kernel — one launch covers all G independent GEMMs of a
 # grouped/depthwise conv (grid gains a leading group dimension; masks carry a
 # leading G axis).  Semantics per group are identical to the 2-D kernel.
+#
+# The block masks are scalar-prefetched FLAT (1-D, row-major over their
+# (G, ·, ·) shape): SMEM pads the minor dim of a multi-dim array to 128
+# words, which at conv shapes (Mb in the thousands, Kb/Nb of a few) blew
+# the 1 MiB SMEM budget.  The emitted bitmap is stored transposed and
+# padded (``bits_tile_shape``) so its block satisfies the (8, 128) rule;
+# the wrappers slice and transpose it back to (G, M//er, N//ec).
 # ---------------------------------------------------------------------------
 
 def _gmm_kernel(out_m_ref, a_m_ref, b_m_ref, a_ref, b_ref, *refs,
@@ -241,6 +252,8 @@ def _gmm_kernel(out_m_ref, a_m_ref, b_m_ref, a_ref, b_ref, *refs,
     i = pl.program_id(1)
     j = pl.program_id(2)
     k = pl.program_id(3)
+    ni = pl.num_programs(1)
+    nj = pl.num_programs(2)
     nk = pl.num_programs(3)
 
     @pl.when(k == 0)
@@ -248,16 +261,14 @@ def _gmm_kernel(out_m_ref, a_m_ref, b_m_ref, a_ref, b_ref, *refs,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     active = (
-        (out_m_ref[g, i, j] != 0)
-        & (a_m_ref[g, i, k] != 0)
-        & (b_m_ref[g, k, j] != 0)
+        (out_m_ref[(g * ni + i) * nj + j] != 0)
+        & (a_m_ref[(g * ni + i) * nk + k] != 0)
+        & (b_m_ref[(g * nk + k) * nj + j] != 0)
     )
 
     @pl.when(active)
     def _issue_mxu():
-        acc_ref[...] += jnp.dot(
-            a_ref[0], b_ref[0], preferred_element_type=jnp.float32
-        )
+        acc_ref[...] += _mxu_dot(a_ref[0], b_ref[0])
 
     @pl.when(k == nk - 1)
     def _write():
@@ -266,7 +277,7 @@ def _gmm_kernel(out_m_ref, a_m_ref, b_m_ref, a_ref, b_ref, *refs,
             o_ref.dtype, emit_gran)
         o_ref[0] = out
         if bits_ref is not None:
-            bits_ref[0] = bits
+            bits_ref[0, 0, 0] = bits
 
 
 def gmm_kernel_variant(has_mult: bool,
@@ -283,6 +294,10 @@ def gmm_kernel_variant(has_mult: bool,
 
     kernel.__name__ = f"_gmm_kernel[mult={int(has_mult)},emit={emit_gran}]"
     return kernel
+
+
+def _flat_i32(x: jnp.ndarray) -> jnp.ndarray:
+    return x.reshape(-1).astype(jnp.int32)
 
 
 def grouped_masked_matmul_kernel(
@@ -332,10 +347,11 @@ def grouped_masked_matmul_kernel(
     if emit_gran is not None:
         er, ec = emit_gran
         assert bm % er == 0 and bn % ec == 0, (emit_gran, bm, bn)
+        cp, rp = bits_tile_shape(bm, bn, er, ec)
         out_specs = [out_specs, pl.BlockSpec(
-            (1, bm // er, bn // ec), lambda gi, i, j, k, *_: (gi, i, j))]
+            (1, 1, 1, cp, rp), lambda gi, i, j, k, *_: (gi, i, j, 0, 0))]
         out_shape = [out_shape,
-                     jax.ShapeDtypeStruct((g, m // er, n // ec), jnp.int32)]
+                     jax.ShapeDtypeStruct((g, ni, nj, cp, rp), jnp.int32)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -350,38 +366,39 @@ def grouped_masked_matmul_kernel(
         out_shape=out_shape,
         interpret=interpret,
     )
-    res = fn(
-        out_mask.astype(jnp.int32),
-        a_mask.astype(jnp.int32),
-        b_mask.astype(jnp.int32),
-        *operands,
-    )
-    if emit_gran is not None:
-        return res[0], res[1]
-    return res
+    res = fn(_flat_i32(out_mask), _flat_i32(a_mask), _flat_i32(b_mask),
+             *operands)
+    if emit_gran is None:
+        return res
+    er, ec = emit_gran
+    return res[0], untile_bits(res[1], bm // er, bn // ec)
 
 
 # ---------------------------------------------------------------------------
 # Grouped compacted kernel — ONE queue spans all groups: slots carry (g, i, j)
 # triples in lexicographic order, so the work-redistribution schedule stays a
 # single uniform stream even when every group contributes only a few tiles
-# (the depthwise regime).
+# (the depthwise regime).  Masks and bits use the same flat / transposed
+# layouts as the predicated family.
 # ---------------------------------------------------------------------------
 
 def _gmm_compact_kernel(
     gg_ref, ii_ref, jj_ref, n_act_ref, a_m_ref, b_m_ref, a_ref, b_ref,
-    *refs, has_mult: bool = False,
+    *refs, tiles: Tuple[int, int], has_mult: bool = False,
     emit_gran: Optional[Tuple[int, int]] = None
 ):
     """Grid = (S, Kb).  Step s processes active tile (gg[s], ii[s], jj[s]).
 
-    One body serves every epilogue combination — the trailing refs are
-    ``[mult?] o [bits?] acc`` per ``_epilogue_refs`` and the writeback goes
-    through ``_apply_epilogue``.  With emission on, each queue slot writes
-    its own (1, bm//er, bn//ec) bits tile; dead slots write zeros (their
-    accumulator never left zero), so the caller's scatter stays exact."""
+    ``tiles`` is the static (Mb, Nb) tile grid the flat masks are laid out
+    on (the queue grid does not carry it).  One body serves every epilogue
+    combination — the trailing refs are ``[mult?] o [bits?] acc`` per
+    ``_epilogue_refs`` and the writeback goes through ``_apply_epilogue``.
+    With emission on, each queue slot writes its own bits tile; dead slots
+    write zeros (their accumulator never left zero), so the caller's
+    scatter stays exact."""
     mult_ref, o_ref, bits_ref, acc_ref = \
         _epilogue_refs(refs, has_mult, emit_gran)
+    ni, nj = tiles
     s = pl.program_id(0)
     k = pl.program_id(1)
     nk = pl.num_programs(1)
@@ -394,13 +411,12 @@ def _gmm_compact_kernel(
     i = ii_ref[s]
     j = jj_ref[s]
     live = s < n_act_ref[0]
-    active = live & (a_m_ref[g, i, k] != 0) & (b_m_ref[g, k, j] != 0)
+    active = (live & (a_m_ref[(g * ni + i) * nk + k] != 0)
+              & (b_m_ref[(g * nk + k) * nj + j] != 0))
 
     @pl.when(active)
     def _issue_mxu():
-        acc_ref[...] += jnp.dot(
-            a_ref[0], b_ref[0], preferred_element_type=jnp.float32
-        )
+        acc_ref[...] += _mxu_dot(a_ref[0], b_ref[0])
 
     @pl.when(k == nk - 1)
     def _write():
@@ -409,19 +425,19 @@ def _gmm_compact_kernel(
             o_ref.dtype, emit_gran)
         o_ref[...] = out
         if bits_ref is not None:
-            bits_ref[...] = bits[None]
+            bits_ref[0] = bits
 
 
 def gmm_compact_kernel_variant(has_mult: bool,
-                               emit_gran: Optional[Tuple[int, int]] = None):
-    """The compact family's variant selector (see ``gmm_kernel_variant``)."""
-    if not has_mult and emit_gran is None:
-        return _gmm_compact_kernel
+                               emit_gran: Optional[Tuple[int, int]] = None,
+                               *, tiles: Tuple[int, int]):
+    """The compact family's variant selector (see ``gmm_kernel_variant``);
+    ``tiles`` is the (Mb, Nb) grid the flat masks index."""
 
     def kernel(gg_ref, ii_ref, jj_ref, n_act_ref, a_m_ref, b_m_ref,
                a_ref, b_ref, *refs):
         _gmm_compact_kernel(gg_ref, ii_ref, jj_ref, n_act_ref, a_m_ref,
-                            b_m_ref, a_ref, b_ref, *refs,
+                            b_m_ref, a_ref, b_ref, *refs, tiles=tiles,
                             has_mult=has_mult, emit_gran=emit_gran)
 
     kernel.__name__ = \
@@ -456,7 +472,7 @@ def grouped_compact_masked_matmul_kernel(
     g, m, k = a.shape
     g2, k2, n = b.shape
     assert g == g2 and k == k2
-    nk = k // bk
+    ni, nj, nk = m // bm, n // bn, k // bk
     (s_cap,) = ii.shape
     assert gg.shape == (s_cap,) and jj.shape == (s_cap,)
 
@@ -470,17 +486,19 @@ def grouped_compact_masked_matmul_kernel(
         in_specs.append(pl.BlockSpec(
             (1, bm, bn), lambda s, k, gg, ii, jj, *_: (gg[s], ii[s], jj[s])))
         operands.append(epilogue_mult.astype(jnp.float32))
-    kernel = gmm_compact_kernel_variant(epilogue_mult is not None, emit_gran)
+    kernel = gmm_compact_kernel_variant(epilogue_mult is not None, emit_gran,
+                                        tiles=(ni, nj))
 
     out_specs = pl.BlockSpec((1, bm, bn), lambda s, k, *_: (s, 0, 0))
     out_shape = jax.ShapeDtypeStruct((s_cap, bm, bn), out_dtype)
     if emit_gran is not None:
         er, ec = emit_gran
         assert bm % er == 0 and bn % ec == 0, (emit_gran, bm, bn)
+        cp, rp = bits_tile_shape(bm, bn, er, ec)
         out_specs = [out_specs, pl.BlockSpec(
-            (1, bm // er, bn // ec), lambda s, k, *_: (s, 0, 0))]
+            (1, cp, rp), lambda s, k, *_: (s, 0, 0))]
         out_shape = [out_shape, jax.ShapeDtypeStruct(
-            (s_cap, bm // er, bn // ec), jnp.int32)]
+            (s_cap, cp, rp), jnp.int32)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,
@@ -500,13 +518,14 @@ def grouped_compact_masked_matmul_kernel(
         ii.astype(jnp.int32),
         jj.astype(jnp.int32),
         n_active.astype(jnp.int32),
-        a_mask.astype(jnp.int32),
-        b_mask.astype(jnp.int32),
+        _flat_i32(a_mask),
+        _flat_i32(b_mask),
         *operands,
     )
-    if emit_gran is not None:
-        return res[0], res[1]
-    return res
+    if emit_gran is None:
+        return res
+    er, ec = emit_gran
+    return res[0], untile_bits(res[1][:, None, None], bm // er, bn // ec)
 
 
 # ---------------------------------------------------------------------------
@@ -533,9 +552,7 @@ def _mm_compact_kernel(
 
     @pl.when(active)
     def _issue_mxu():
-        acc_ref[...] += jnp.dot(
-            a_ref[...], b_ref[...], preferred_element_type=jnp.float32
-        )
+        acc_ref[...] += _mxu_dot(a_ref[...], b_ref[...])
 
     @pl.when(k == nk - 1)
     def _write():
@@ -565,9 +582,7 @@ def _mm_compact_epilogue_kernel(
 
     @pl.when(active)
     def _issue_mxu():
-        acc_ref[...] += jnp.dot(
-            a_ref[...], b_ref[...], preferred_element_type=jnp.float32
-        )
+        acc_ref[...] += _mxu_dot(a_ref[...], b_ref[...])
 
     @pl.when(k == nk - 1)
     def _write():

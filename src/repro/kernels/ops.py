@@ -19,7 +19,8 @@ THE masked-GEMM entry point is ``sparse_gemm(a, b, masks, spec)``:
 
 Handles:
   * automatic interpret-mode selection (CPU backend → interpret=True, so the
-    whole framework is testable in this container while targeting TPU),
+    whole framework is testable on a CPU host while targeting TPU; every
+    other backend compiles the kernels),
   * block-alignment padding (MXU-aligned defaults bm=bk=bn=128; padded
     blocks are marked inactive so they are skipped, not computed),
   * the compact (work-redistribution) launch path, including the active-
@@ -44,16 +45,16 @@ from typing import Any, List, NamedTuple, Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 
-from . import queue_builder as _queue_builder
 from . import ref, stats
 from .bitmap_scan import bitmap_scan_kernel
 from .masked_matmul import (
     grouped_compact_masked_matmul_kernel,
     grouped_masked_matmul_kernel,
 )
+from .queue_builder import build_queue
 from .relu_encode import relu_encode_kernel
 from .shapes import (
-    block_bitmap, ceil_to, grid_shape, pad3, pad_mask3, pad_to,
+    block_bitmap, ceil_to, grid_shape, pad3, pad_mask3, pad_to, slab_rows,
 )
 
 # MXU-native tile. Tests sweep smaller tiles in interpret mode.
@@ -84,24 +85,12 @@ def normalize_epilogue(epilogue) -> Tuple[str, ...]:
 
 
 def _use_interpret(interpret: Optional[bool]) -> bool:
+    """Interpret mode only where asked, or automatically on the CPU backend
+    (where tests run); any other backend compiles the kernels, so a run
+    that meant to use the chip fails instead of quietly interpreting."""
     if interpret is not None:
         return interpret
-    return jax.default_backend() != "tpu"
-
-
-def build_queue(
-    bitmap: jnp.ndarray,
-    *,
-    capacity: int,
-    builder: str = "prefix_sum",
-    interpret: Optional[bool] = None,
-) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Active-tile queue from a tile bitmap — re-export of
-    ``kernels.queue_builder.build_queue`` with auto interpret resolution
-    (the builder dispatch itself lives next to the prefix-sum kernel)."""
-    return _queue_builder.build_queue(
-        bitmap, capacity=capacity, builder=builder,
-        interpret=_use_interpret(interpret))
+    return jax.default_backend() == "cpu"
 
 
 # ---------------------------------------------------------------------------
@@ -452,7 +441,7 @@ def _dispatch(a, b, masks: GemmMasks, spec: GemmSpec, mult):
         # coordinate back out of the fused row index.
         fi, jj, n_live_v = build_queue(
             om.reshape(g * ni, nj), capacity=s_cap,
-            builder=spec.queue_builder, interpret=itp)
+            builder=spec.queue_builder)
         gg = fi // ni
         ii = fi % ni
         n_live = n_live_v[0]
@@ -542,8 +531,9 @@ def bitmap_scan(
     """
     m, n = x.shape
     bm, bn = block
-    lr = bm * max(1, -(-8 // bm))
-    mp, np_ = ceil_to(m, lr), ceil_to(n, bn)
+    np_ = ceil_to(n, bn)
+    lr = slab_rows(m, bm, np_)
+    mp = ceil_to(m, lr)
     stats.record(f"scan_pallas:{kind}")
     with stats.lifecycle_scope("scan", kind):
         x_p = pad_to(x, mp, np_)
@@ -561,18 +551,19 @@ def relu_encode(
     """Fused relu(z) + block bitmap at granularity ``block``.
 
     Pads, launches, unpads.  The launch tile is decoupled from the bitmap
-    granularity (≥8 rows per grid step), so fine granularities — down to
-    per-row bitmaps, which the conv path needs for im2col-derivable
-    metadata — stay cheap to launch.
+    granularity (``shapes.slab_rows``: lane-dense slabs of up to a few
+    thousand rows), so fine granularities — down to per-row bitmaps, which
+    the conv path needs for im2col-derivable metadata — stay cheap to
+    launch.
 
     This is THE forward-pass bitmap computation: one fused pass per
     activation per step; every downstream mask is derived from its result.
     """
     m, n = z.shape
     bm, bn = block
-    # Launch slab: a multiple of the bitmap granularity covering >=8 rows.
-    lr = bm * max(1, -(-8 // bm))
-    mp, np_ = ceil_to(m, lr), ceil_to(n, bn)
+    np_ = ceil_to(n, bn)
+    lr = slab_rows(m, bm, np_)
+    mp = ceil_to(m, lr)
     stats.record("encode:act")
     with stats.lifecycle_scope("encode", "act"):
         z_p = pad_to(z, mp, np_)

@@ -1,26 +1,29 @@
-"""On-device work-queue construction: stream compaction by prefix sum.
+"""Work-queue construction: stream compaction by prefix sum.
 
-The compacted schedule (masked_matmul.compact_masked_matmul_kernel) consumes
-an explicit queue of active output-tile coordinates ``(ii, jj, n_active)``.
-The seed built that queue with ``jnp.argsort`` over the flattened (Mb, Nb)
-tile bitmap — an O(T log T) sort sitting on the critical path of every
-backward step, growing with model size.  The WDU principle (paper §4.6, and
-the SparseTrain/TensorDash lesson) is that scheduling metadata must be a
-near-free byproduct of the dataflow, so this kernel replaces the sort with
-an exclusive-prefix-sum *stream compaction*: O(T) work, one pass.
+The compacted schedule (masked_matmul.grouped_compact_masked_matmul_kernel)
+consumes an explicit queue of active output-tile coordinates
+``(ii, jj, n_active)``.  The seed built that queue with ``jnp.argsort``
+over the flattened (Mb, Nb) tile bitmap — an O(T log T) sort sitting on
+the critical path of every backward step, growing with model size.  The
+WDU principle (paper §4.6, and the SparseTrain/TensorDash lesson) is that
+scheduling metadata must be a near-free byproduct of the dataflow, so the
+default builder replaces the sort with an exclusive-prefix-sum *stream
+compaction*: O(T) work, one pass.
 
-Algorithm (classic GPU/TPU stream compaction, done blockwise):
+Algorithm (classic stream compaction):
 
   1. flatten the bitmap row-major (the WDU's "lexicographically smallest
      state tuple first" order is exactly row-major (i, j));
-  2. walk it in launch blocks of L elements (sequential TPU grid);
-  3. inside a block: exclusive prefix sum of the flags (a local cumsum);
-  4. across blocks: a scalar carry in SMEM accumulates the running count,
-     so element t's queue slot is ``carry + local_exclusive_scan[t]``;
-  5. each live element stores its (i, j) = (t // Nb, t % Nb) at its slot.
+  2. exclusive prefix sum of the live flags gives each live element its
+     queue slot;
+  3. each live element stores its (i, j) = (t // Nb, t % Nb) at its slot.
      Dead elements — and live elements past ``capacity`` (overflow) — are
-     steered to a dump slot one past the queue, so stores are unconditional
-     and overflow never corrupts slots [0, capacity).
+     steered to a dump slot one past the queue, so the scatter is
+     unconditional and overflow never corrupts slots [0, capacity).
+
+The bitmap holds tile counts, not elements, so the compaction is a few
+small XLA ops (``cumsum`` + one scatter) rather than a Pallas kernel:
+Mosaic has no lowering for an in-kernel ``cumsum``.
 
 The emitted order is *identical* to the retained argsort reference (both
 are row-major-stable); ``core.workredist.static_queue_order`` is the
@@ -29,138 +32,44 @@ executable statement of that contract and the property suite
 """
 from __future__ import annotations
 
-import functools
-from typing import Optional, Tuple
+from typing import Tuple
 
-import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 from . import stats
 
-try:  # TPU-specific helpers; present in jax>=0.4 under .tpu
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
 
-# Launch-block length for the flattened bitmap walk.  Bitmaps are tiny
-# (tile counts, not elements), so one VPU-friendly row per grid step is
-# plenty; the carry makes the block size a pure tuning knob.
-DEFAULT_QUEUE_BLOCK = 256
-
-
-def _queue_builder_kernel(bm_ref, ii_ref, jj_ref, cnt_ref, carry_ref,
-                          *, cap: int, nj: int, lb: int):
-    """Grid = (T // lb,).  Step b compacts flat elements [b*lb, (b+1)*lb)."""
-    b = pl.program_id(0)
-    nb = pl.num_programs(0)
-
-    @pl.when(b == 0)
-    def _init():
-        carry_ref[0] = 0
-        # Dead queue slots must hold VALID coordinates: the consumer gathers
-        # operand tiles at (ii[s], jj[s]) even for s >= n_active.
-        ii_ref[...] = jnp.zeros_like(ii_ref)
-        jj_ref[...] = jnp.zeros_like(jj_ref)
-
-    flags = (bm_ref[...] != 0).astype(jnp.int32)[0]     # (lb,)
-    excl = jnp.cumsum(flags) - flags                     # exclusive scan
-    base = carry_ref[0]                                  # carry across blocks
-
-    def _store(e, _):
-        t = b * lb + e                                   # flat bitmap index
-        # Live → its compacted slot; dead or overflow → the dump slot.
-        slot = jnp.where(flags[e] != 0, base + excl[e], cap)
-        slot = jnp.minimum(slot, cap)
-        ii_ref[pl.dslice(slot, 1), :] = jnp.full((1, 1), t // nj, jnp.int32)
-        jj_ref[pl.dslice(slot, 1), :] = jnp.full((1, 1), t % nj, jnp.int32)
-        return 0
-
-    jax.lax.fori_loop(0, lb, _store, 0)
-    carry_ref[0] = base + jnp.sum(flags)
-
-    @pl.when(b == nb - 1)
-    def _emit_count():
-        cnt_ref[0, 0] = carry_ref[0]
+def _prefix_sum_queue(flat: jnp.ndarray, capacity: int, nb: int):
+    """Exclusive-prefix-sum compaction of the flat {0,1} tile vector."""
+    live = flat != 0
+    flags = live.astype(jnp.int32)
+    slot = jnp.where(live, jnp.cumsum(flags) - flags, capacity)
+    slot = jnp.minimum(slot, capacity)          # overflow -> dump slot
+    t = jnp.arange(flat.shape[0], dtype=jnp.int32)
+    # Dead queue slots must hold VALID coordinates: the consumer gathers
+    # operand tiles at (ii[s], jj[s]) even for s >= n_active — zeros.
+    ii = jnp.zeros((capacity + 1,), jnp.int32).at[slot].set(t // nb)
+    jj = jnp.zeros((capacity + 1,), jnp.int32).at[slot].set(t % nb)
+    return ii[:capacity], jj[:capacity], flags.sum().reshape(1)
 
 
-def build_queue_kernel(
-    bitmap: jnp.ndarray,          # (Mb, Nb) int32 tile bitmap
-    *,
-    capacity: int,
-    launch_block: int = DEFAULT_QUEUE_BLOCK,
-    interpret: bool = False,
-) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Compact ``bitmap`` into ``(ii, jj, n_live)`` — no sort anywhere.
-
-    Returns row-major (WDU reference order) coordinates of the set bits:
-    ``ii``/``jj`` are (capacity,) int32, zero-padded past the live count;
-    ``n_live`` is (1,) int32 and is the TRUE number of set bits (it may
-    exceed ``capacity`` — callers use that to trigger the overflow
-    fallback; only the first ``min(n_live, capacity)`` slots are filled).
-    """
-    mb, nb = bitmap.shape
-    t = mb * nb
-    lb = min(launch_block, t)
-    tp = (t + lb - 1) // lb * lb
-    flat = bitmap.reshape(-1).astype(jnp.int32)
-    if tp != t:
-        flat = jnp.pad(flat, (0, tp - t))                # padding is dead
-    blocks = flat.reshape(tp // lb, lb)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=0,
-        grid=(tp // lb,),
-        in_specs=[pl.BlockSpec((1, lb), lambda b: (b, 0))],
-        out_specs=[
-            pl.BlockSpec((capacity + 1, 1), lambda b: (0, 0)),
-            pl.BlockSpec((capacity + 1, 1), lambda b: (0, 0)),
-            pl.BlockSpec((1, 1), lambda b: (0, 0)),
-        ],
-        scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
-    )
-    fn = pl.pallas_call(
-        functools.partial(_queue_builder_kernel, cap=capacity, nj=nb, lb=lb),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((capacity + 1, 1), jnp.int32),  # +dump slot
-            jax.ShapeDtypeStruct((capacity + 1, 1), jnp.int32),
-            jax.ShapeDtypeStruct((1, 1), jnp.int32),
-        ],
-        interpret=interpret,
-    )
-    ii, jj, cnt = fn(blocks)
-    return ii[:capacity, 0], jj[:capacity, 0], cnt[0]
+def _argsort_queue(flat: jnp.ndarray, capacity: int, nb: int):
+    """The retained O(T log T) reference: stable descending argsort of the
+    {0,1} vector (active indices first, row-major within each class)."""
+    order = jnp.argsort(-flat, stable=True)[:capacity]
+    if order.shape[0] < capacity:           # capacity may exceed T
+        order = jnp.pad(order, (0, capacity - order.shape[0]))
+    ii = (order // nb).astype(jnp.int32)
+    jj = (order % nb).astype(jnp.int32)
+    # Dead slots must carry valid (in-range) coords for the consumer's
+    # gathers; zero them like the prefix-sum builder.
+    live = jnp.arange(capacity) < flat.sum()
+    ii = jnp.where(live, ii, 0)
+    jj = jnp.where(live, jj, 0)
+    return ii, jj, flat.sum().reshape(1)
 
 
-# ---------------------------------------------------------------------------
-# Builder dispatch — the queue-construction side of the sparse_gemm call
-# contract.  kernels/ops.py's dispatcher calls ONLY this function; the
-# argsort reference lives here next to the kernel it is the oracle for.
-# ---------------------------------------------------------------------------
-
-def _parse_version(v: str):
-    """Leading-digit parse per component: '0.4.27rc1' → (0, 4, 27); any
-    unparseable component compares as 0 (never an import-time crash)."""
-    import re
-    out = []
-    for part in v.split(".")[:3]:
-        m = re.match(r"\d+", part)
-        out.append(int(m.group()) if m else 0)
-    return tuple(out)
-
-
-_JAX_VERSION = _parse_version(jax.__version__)
-
-
-def _stable_argsort_desc(flat: jnp.ndarray) -> jnp.ndarray:
-    """Stable descending argsort of a {0,1} vector (active indices first,
-    row-major within each class) — the retained O(T log T) queue-builder
-    reference.  ``stable=`` only exists from jax 0.4.27; earlier releases
-    sort stably by default, so the kwarg is version-gated, not assumed."""
-    if _JAX_VERSION >= (0, 4, 27):
-        return jnp.argsort(-flat, stable=True)
-    return jnp.argsort(-flat)  # pre-0.4.27 argsort is stable by default
+_BUILDERS = {"prefix_sum": _prefix_sum_queue, "argsort": _argsort_queue}
 
 
 def build_queue(
@@ -168,39 +77,24 @@ def build_queue(
     *,
     capacity: int,
     builder: str = "prefix_sum",
-    interpret: bool = False,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Active-tile queue ``(ii, jj, n_live)`` from a (Mb, Nb) tile bitmap.
 
     Queue order is the WDU's "lexicographically smallest state tuple first"
     — row-major (i, j); ``core.workredist.static_queue_order`` is the
-    reference.  ``n_live`` (1,) is the TRUE set-bit count (may exceed
-    ``capacity``; slots past it are zero-padded).
+    reference.  ``ii``/``jj`` are (capacity,) int32, zero-padded past the
+    live count; ``n_live`` (1,) is the TRUE set-bit count (it may exceed
+    ``capacity`` — callers use that to trigger the overflow fallback).
 
-    builder="prefix_sum" (default): the Pallas blockwise exclusive-prefix-
-    sum stream compaction above — O(T), no sort on the critical path.
-    builder="argsort": the seed's O(T log T) sort, kept as the reference
-    and fallback.  Each construction is counted by ``stats`` as
-    ``queue:<builder>``.
+    builder="prefix_sum" (default): the O(T) stream compaction above — no
+    sort on the critical path.  builder="argsort": the seed's O(T log T)
+    sort, kept as the reference.  Each construction is counted by
+    ``stats`` as ``queue:<builder>``.
     """
-    mb, nb = bitmap.shape
-    stats.record(f"queue:{builder}")
-    if builder == "argsort":
-        with stats.lifecycle_scope("queue", builder):
-            flat = bitmap.reshape(-1)
-            order = _stable_argsort_desc(flat)[:capacity]
-            if order.shape[0] < capacity:       # capacity may exceed T
-                order = jnp.pad(order, (0, capacity - order.shape[0]))
-            ii = (order // nb).astype(jnp.int32)
-            jj = (order % nb).astype(jnp.int32)
-            # Dead slots must carry valid (in-range) coords for the
-            # consumer's gathers; zero them like the prefix-sum builder.
-            live = jnp.arange(capacity) < flat.sum()
-            ii = jnp.where(live, ii, 0)
-            jj = jnp.where(live, jj, 0)
-            return ii, jj, flat.sum().reshape(1)
-    if builder != "prefix_sum":
+    if builder not in _BUILDERS:
         raise ValueError(f"unknown queue builder: {builder!r}")
+    _, nb = bitmap.shape
+    stats.record(f"queue:{builder}")
     with stats.lifecycle_scope("queue", builder):
-        return build_queue_kernel(bitmap, capacity=capacity,
-                                  interpret=interpret)
+        flat = bitmap.reshape(-1).astype(jnp.int32)
+        return _BUILDERS[builder](flat, capacity, nb)

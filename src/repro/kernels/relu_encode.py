@@ -10,9 +10,10 @@ exactly as in the paper.
 
 The bitmap granularity (gr, gc) is decoupled from the launch tile (lr, lc):
 one kernel invocation covers an (lr, lc) slab of the activation and reduces
-it to an (lr//gr, lc//gc) sub-bitmap with a single reshape-max, so even
-per-row granularities (needed by the conv path, where the bitmap must stay
-spatially addressable for im2col derivation) launch with a coarse grid.
+it to an (lr//gr, lc//gc) sub-bitmap with two 0/1 indicator matmuls
+(``bits.any_nonzero_t``), so even per-row granularities (needed by
+the conv path, where the bitmap must stay spatially addressable for im2col
+derivation) launch with a coarse grid.
 """
 from __future__ import annotations
 
@@ -22,20 +23,16 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from .bits import any_nonzero_t, bits_tile_shape, untile_bits
 
 
 def _relu_encode_kernel(z_ref, y_ref, bm_ref, *, gr: int, gc: int):
     y = jnp.maximum(z_ref[...], jnp.zeros((), dtype=z_ref.dtype))
     y_ref[...] = y
-    lr, lc = y.shape
-    yb = y.reshape(lr // gr, gr, lc // gc, gc)
-    # y >= 0 everywhere, so max > 0 <=> the sub-block has a live activation.
-    bm_ref[...] = (jnp.max(yb.astype(jnp.float32), axis=(1, 3)) > 0) \
-        .astype(jnp.int32)
+    # y >= 0 everywhere, so any-nonzero <=> the sub-block has a live
+    # activation.  Stored transposed and padded (lane-dense); the wrapper
+    # transposes back.
+    bm_ref[0, 0] = any_nonzero_t(y, gr, gc)
 
 
 def relu_encode_kernel(
@@ -50,8 +47,8 @@ def relu_encode_kernel(
     """Returns (relu(z), bitmap) with bitmap shape (M//bm, N//bn) int32.
 
     (bm, bn) is the BITMAP granularity; (lr, lc) the launch tile (defaults:
-    whole array — callers size it; the ops wrapper picks ~8-row slabs so
-    fine granularities never explode the grid).
+    whole array — callers size it; the ops wrapper picks slabs of a few
+    hundred to a few thousand rows).
     """
     m, n = z.shape
     lr = lr or m
@@ -59,19 +56,20 @@ def relu_encode_kernel(
     assert m % lr == 0 and n % lc == 0, (z.shape, lr, lc)
     assert lr % bm == 0 and lc % bn == 0, (lr, lc, bm, bn)
     ni, nj = m // lr, n // lc
-    fr, fc = lr // bm, lc // bn
+    cp, rp = bits_tile_shape(lr, lc, bm, bn)
     fn = pl.pallas_call(
         functools.partial(_relu_encode_kernel, gr=bm, gc=bn),
         grid=(ni, nj),
         in_specs=[pl.BlockSpec((lr, lc), lambda i, j: (i, j))],
         out_specs=[
             pl.BlockSpec((lr, lc), lambda i, j: (i, j)),
-            pl.BlockSpec((fr, fc), lambda i, j: (i, j)),
+            pl.BlockSpec((1, 1, cp, rp), lambda i, j: (i, j, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((m, n), z.dtype),
-            jax.ShapeDtypeStruct((m // bm, n // bn), jnp.int32),
+            jax.ShapeDtypeStruct((ni, nj, cp, rp), jnp.int32),
         ],
         interpret=interpret,
     )
-    return fn(z)
+    y, bits = fn(z)
+    return y, untile_bits(bits, lr // bm, lc // bn)

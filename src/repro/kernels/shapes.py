@@ -9,6 +9,7 @@ zero policy or kernel knowledge, so any layer may import it.
 """
 from __future__ import annotations
 
+import math
 from typing import Optional, Tuple
 
 import jax.numpy as jnp
@@ -72,6 +73,22 @@ def block_bitmap(x: jnp.ndarray, b0: int, b1: int) -> jnp.ndarray:
     m, n = x.shape
     return ref.block_any_nonzero(pad_to(x, ceil_to(m, b0), ceil_to(n, b1)),
                                  b0, b1)
+
+
+# f32 elements of lane-padded slab per row-slab kernel block (1 MiB).
+SLAB_ELEMS = 1 << 18
+
+
+def slab_rows(m: int, gr: int, cols: int) -> int:
+    """Launch-slab height for a row-slab kernel over an (M, cols) array
+    whose bitmap has row granularity ``gr``: a multiple of lcm(gr, 128)
+    (so the slab's transposed bitmap is lane-dense) that divides M rounded
+    up to that unit (so no padding beyond one unit), with at most about
+    ``SLAB_ELEMS`` f32 elements of lane-padded slab per block."""
+    unit = math.lcm(gr, 128)
+    q = ceil_to(m, unit) // unit
+    cap = max(1, SLAB_ELEMS // ceil_to(cols, 128) // unit)
+    return unit * max(d for d in range(1, min(q, cap) + 1) if q % d == 0)
 
 
 def grid_shape(dims: Tuple[int, ...], block: Tuple[int, ...]) -> Tuple[int, ...]:

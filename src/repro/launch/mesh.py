@@ -16,25 +16,21 @@ def make_abstract_mesh(shape: Sequence[int],
                        axes: Sequence[str]) -> AbstractMesh:
     """Device-free AbstractMesh from parallel (shape, axes) sequences.
 
-    jax's ``AbstractMesh`` constructor takes a single tuple of
-    ``(axis_name, size)`` pairs (and has changed signature across jax
-    releases) — this helper is the ONE place that knows that, so tests and
-    library code agree on a construction API mirroring ``jax.make_mesh``.
+    The one place that builds an ``AbstractMesh``, so tests and library
+    code agree on a construction API mirroring ``jax.make_mesh``.
     """
     assert len(shape) == len(axes), (shape, axes)
-    try:
-        # jax <= 0.4.x: AbstractMesh(shape_tuple) of (name, size) pairs.
-        return AbstractMesh(tuple(zip(axes, shape)))
-    except TypeError:
-        # jax >= 0.5: AbstractMesh(axis_sizes, axis_names).
-        return AbstractMesh(tuple(shape), tuple(axes))
+    return AbstractMesh(tuple(shape), tuple(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 = 256 chips/pod ("data","model"); multi_pod adds a 2-pod axis."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    # Auto axes: the model shards through with_sharding_constraint, which
+    # refuses the Explicit axes jax.make_mesh defaults to.
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_test_mesh(n_data: int = 2, n_model: int = 2):

@@ -197,14 +197,9 @@ def _embed_lookup(embed: jnp.ndarray, tokens: jnp.ndarray) -> jnp.ndarray:
         return jax.lax.psum(out, vaxis)
 
     out_spec = P(*tok_spec, None)
-    # jax.shard_map is only a top-level name on newer jax; fall back to the
-    # experimental location that jax 0.4.x ships.
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:
-        from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh,
-                     in_specs=(P(vaxis, None), tok_spec),
-                     out_specs=out_spec)(embed, tokens)
+    return jax.shard_map(f, mesh=mesh,
+                         in_specs=(P(vaxis, None), tok_spec),
+                         out_specs=out_spec, check_vma=False)(embed, tokens)
 
 
 def _period_layout(cfg) -> Tuple[Tuple[str, bool], ...]:

@@ -43,9 +43,7 @@ def compressed_psum(grads: Any, err: Any, axis_names) -> Tuple[Any, Any]:
     (mean_grads_f32, new_err)."""
     n = 1
     for a in (axis_names if isinstance(axis_names, (tuple, list)) else [axis_names]):
-        # jax.lax.axis_size only exists on newer jax; psum(1) is the
-        # version-stable way to read a mapped axis size inside shard_map.
-        n = n * jax.lax.psum(1, a)
+        n = n * jax.lax.axis_size(a)
 
     def one(g, e):
         q, scale, e1 = quantize(g, e)

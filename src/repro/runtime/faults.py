@@ -184,7 +184,7 @@ def _collective_hook(site: str, contrib, axis_name):
     f.fired += 1
     name = axis_name if isinstance(axis_name, str) else axis_name[0]
     idx = lax.axis_index(name)
-    n = lax.psum(1, name)
+    n = lax.axis_size(name)
     keep = (idx != jnp.mod(f.seed, n)).astype(contrib.dtype)
     return contrib * keep
 
@@ -468,7 +468,6 @@ def _case_collective_drop() -> MatrixRow:
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.sharding import collectives
@@ -493,9 +492,9 @@ def _case_collective_drop() -> MatrixRow:
             return collectives.sparse_psum(
                 xs[0], bs[0], gran, axis_name="data", cutoff=cutoff,
                 return_bits=True)
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             body, mesh=mesh, in_specs=(P("data"), P("data")),
-            out_specs=(P(), P()), check_rep=False))
+            out_specs=(P(), P()), check_vma=False))
 
     guard = StepGuard()
     fault = arm(Fault("collective:allreduce", "drop_contrib", seed=0))

@@ -23,16 +23,15 @@ explicit:
     backward pass compress the wire traffic, everything else takes the
     tagged dense psum.
 
-``check_rep=False`` throughout: the bodies route through Pallas kernels
-(custom_vjp + pallas_call), for which shard_map's replication checker has
-no rules.
+``check_vma=False`` throughout: the bodies route through Pallas kernels
+(custom_vjp + pallas_call), for which shard_map's varying-manual-axes
+checker has no rules.
 """
 from __future__ import annotations
 
 from typing import Any, Callable, Optional, Tuple
 
 import jax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.sharding import collectives, partition
@@ -73,9 +72,9 @@ def make_spmd_grad_fn(loss_fn: Callable[[Any, Any], Any], mesh: Mesh, *,
         loss = collectives.psum_scalar(loss, axes)
         return loss * inv, jax.tree.map(lambda g: g * inv, grads)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(P(), P(tuple(axes))),
-        out_specs=(P(), P()), check_rep=False))
+        out_specs=(P(), P()), check_vma=False))
 
 
 def make_spmd_train_step(cfg, opt_cfg, mesh: Mesh, *,
@@ -110,7 +109,7 @@ def make_spmd_train_step(cfg, opt_cfg, mesh: Mesh, *,
         metrics["loss"] = loss
         return params, opt_state, metrics
 
-    step = shard_map(body, mesh=mesh,
-                     in_specs=(P(), P(), P(tuple(axes))),
-                     out_specs=(P(), P(), P()), check_rep=False)
+    step = jax.shard_map(body, mesh=mesh,
+                         in_specs=(P(), P(), P(tuple(axes))),
+                         out_specs=(P(), P(), P()), check_vma=False)
     return jax.jit(step, donate_argnums=(0, 1))

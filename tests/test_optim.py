@@ -69,7 +69,6 @@ def test_compressed_psum_shard_map():
     """Mechanics of the int8 EF all-reduce under shard_map (axis size 1 on
     CPU; numerics of quantize path still exercised end-to-end)."""
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
     g = {"w": jnp.asarray([0.1, -0.5, 0.25, 3.0])}
     err = init_error_state(g)
@@ -77,8 +76,8 @@ def test_compressed_psum_shard_map():
     def f(g, err):
         return compressed_psum(g, err, "data")
 
-    out, err2 = shard_map(f, mesh=mesh, in_specs=(P(), P()),
-                          out_specs=(P(), P()))(g, err)
+    out, err2 = jax.shard_map(f, mesh=mesh, in_specs=(P(), P()),
+                              out_specs=(P(), P()))(g, err)
     scale = float(jnp.max(jnp.abs(g["w"]))) / 127.0
     np.testing.assert_allclose(out["w"], g["w"], atol=scale + 1e-7)
     # error feedback holds the residual

@@ -1,4 +1,4 @@
-"""The compaction contract: the Pallas prefix-sum queue builder emits
+"""The compaction contract: the prefix-sum queue builder emits
 EXACTLY the WDU reference order (``core.workredist.static_queue_order`` —
 row-major "lexicographically smallest state tuple first"), bit-for-bit,
 for any bitmap — and the compact matmul path never sorts on the default
@@ -151,7 +151,7 @@ def test_build_queue_rejects_unknown_builder():
 def test_build_queue_jits_and_batches_under_vmap_shapes():
     """The builder must be jit-safe (it sits inside jitted train steps)."""
     bm = jnp.asarray(np.eye(5, dtype=np.int32))
-    f = jax.jit(lambda m: ops.build_queue(m, capacity=25, interpret=True))
+    f = jax.jit(lambda m: ops.build_queue(m, capacity=25))
     ii, jj, nl = f(bm)
     ri, rj, rn = static_queue_order(np.eye(5), capacity=25)
     assert int(nl[0]) == rn

@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import policy as pol
@@ -75,9 +74,9 @@ def _reduce_fn(gran, cutoff, mesh=None):
         return sparse_psum(x[0], b[0], gran, axis_name=axes, cutoff=cutoff,
                            return_bits=True)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(P(axes), P(axes)),
-        out_specs=(P(), P()), check_rep=False))
+        out_specs=(P(), P()), check_vma=False))
 
 
 # ---------------------------------------------------------------------------
@@ -132,9 +131,9 @@ def test_dense_psum_records_collective_key():
     mesh = _data_mesh()
     x = jnp.ones((jax.device_count(), 4, 4), jnp.float32)
     stats.reset()
-    fn = jax.jit(shard_map(lambda v: dense_psum(v[0], axis_name="data"),
+    fn = jax.jit(jax.shard_map(lambda v: dense_psum(v[0], axis_name="data"),
                            mesh=mesh, in_specs=(P("data"),),
-                           out_specs=P(), check_rep=False))
+                           out_specs=P(), check_vma=False))
     jax.block_until_ready(fn(x))
     assert stats.counts().get("collective:dense", 0) == 1
 
@@ -161,8 +160,8 @@ def test_psum_grads_routes_by_registry():
         grads = jax.grad(loss)(p)
         return psum_grads(grads, axis_name=("data",), cutoff=0.5)
 
-    fn = jax.jit(shard_map(body, mesh=mesh, in_specs=(P(),),
-                           out_specs=P(), check_rep=False))
+    fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P(),),
+                           out_specs=P(), check_vma=False))
     out = fn(params)
     jax.block_until_ready(out)
     c = stats.counts()

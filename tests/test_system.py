@@ -18,6 +18,34 @@ from repro.models.cnn import build_cnn
 REPO = os.path.join(os.path.dirname(__file__), "..")
 
 
+def test_chip_smoke_refuses_without_a_tpu():
+    """chip_smoke.py fails, and prints no result line, when JAX finds no
+    TPU: a chip run never falls back to the CPU."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, os.path.join(REPO, "chip_smoke.py")],
+                         env=env, capture_output=True, text=True, timeout=120)
+    assert out.returncode != 0, out.stdout
+    assert '"ok": true' not in out.stdout
+    assert "needs a TPU" in out.stderr
+
+
+def test_compile_cache_dir_follows_env(monkeypatch):
+    """The entry points' cache helper leaves a JAX_COMPILATION_CACHE_DIR
+    to JAX and otherwise uses the repository's fixed .jax_cache."""
+    from repro.launch import cache
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv(cache.ENV_VAR, "/elsewhere/cache")
+        assert cache.use_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == prev
+        monkeypatch.delenv(cache.ENV_VAR)
+        want = os.path.join(os.path.abspath(REPO), ".jax_cache")
+        assert cache.use_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+
 def test_lm_training_learns():
     """examples-style LM training descends on the synthetic stream."""
     cfg = SMOKE_ARCHS["smollm-360m"]
