@@ -46,12 +46,18 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.stats import LIFECYCLE_KINDS
+
 from .report import Violation
 
-# One lifecycle tag: repro:<kind>[:<detail>]:<seq>.  Tags never contain
+# One scope tag: repro:<kind>[:<detail>]:<seq>.  Tags never contain
 # "/", "(" or ")", which is exactly what the name-stack string uses for
 # nesting and transform wrappers — so this match always grabs a whole tag.
-TAG_RE = re.compile(r"repro:[^/()]+")
+# An equation's region is its innermost LIFECYCLE tag: attribution tags
+# (layout, pool, norm, merge, pad, scatter) only say where device time
+# goes, so a pad nested in a gemm region stays in that region and a layout
+# scope around data preparation grounds nothing.
+TAG_RE = re.compile(r"repro:([^/():]+)[^/()]*")
 LAYER_RE = re.compile(r"layer:[^/()]+")
 
 # Primitives that forward bitmap/array content without computing anything
@@ -76,7 +82,7 @@ COLLECTIVE_PRIMS = {
 
 @dataclasses.dataclass
 class ParsedTag:
-    kind: str          # encode | scan | derive | queue | gemm | fallback
+    kind: str          # one of stats.LIFECYCLE_KINDS
     detail: str        # e.g. "act", "grad", "compact:1"
     tag: str           # the full unique tag (region identity)
 
@@ -144,7 +150,8 @@ class _Walk:
     def _visit(self, jaxpr, outer_stack: str, depth: int):
         for eqn in jaxpr.eqns:
             stack = outer_stack + "/" + str(eqn.source_info.name_stack)
-            tags = TAG_RE.findall(stack)
+            tags = [m.group(0) for m in TAG_RE.finditer(stack)
+                    if m.group(1) in LIFECYCLE_KINDS]
             layers = LAYER_RE.findall(stack)
             info = EqnInfo(
                 eqn=eqn,

@@ -51,9 +51,7 @@ KNOWN_KEY_HEADS = {"encode", "scan", "scan_pallas", "emit", "queue", "gemm",
                    # runtime guard layer (docs/resilience.md):
                    "guard", "registry", "fallback",
                    # sharded collectives (docs/sharding.md):
-                   "collective",
-                   # legacy heads normalized by stats._KEY_ALIASES:
-                   "mm", "gmm", "grouped_mm"}
+                   "collective"}
 FALLBACK_KEY = "conv:dense_fallback"
 _ALLOW_RE = re.compile(r"repro-lint:\s*allow\(([A-Z_, ]+)\)")
 

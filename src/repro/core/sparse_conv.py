@@ -195,11 +195,13 @@ def _encode_conv_act(x_pre: jnp.ndarray, policy: SparsityPolicy,
     """(relu(x_pre), SparseTensor over the (N·H·W, C) view) — ONE fused
     encode (pallas) or one counted scan (xla_ref) per activation per step."""
     n, h, w, c = x_pre.shape
-    x2d = x_pre.reshape(n * h * w, c)
+    with stats.lifecycle_scope("layout", "fp"):
+        x2d = x_pre.reshape(n * h * w, c)
     if policy.kernel_impl == "pallas":
         y2d, fb = kops.relu_encode(x2d, block=(1, gc),
                                    interpret=policy.interpret)
-        x = y2d.reshape(n, h, w, c)
+        with stats.lifecycle_scope("layout", "fp"):
+            x = y2d.reshape(n, h, w, c)
     else:
         x = jnp.maximum(x_pre, jnp.zeros((), x_pre.dtype))
         fb = scan_bitmap(x.reshape(n * h * w, c), (1, gc), kind="act")
@@ -232,6 +234,12 @@ def _grad_sparse_tensor(dy, dy32: jnp.ndarray, policy: SparsityPolicy,
 # ---------------------------------------------------------------------------
 # The engine — one forward/backward pair for every conv flavour
 # ---------------------------------------------------------------------------
+#
+# Each stage's data-side re-layout (im2col, dy dilation, weight flips and
+# transposes, the σ′ multiplier, group and output reshapes) runs under a
+# ``repro:layout:<fp|bp|wg>`` scope, opened between the derive and gemm
+# scopes and never around them, so a profile reads the layout work apart
+# from the sparse engine's own.
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6))
 def _conv_engine(x: jnp.ndarray, w: jnp.ndarray, stride: int, padding: str,
@@ -280,10 +288,11 @@ def _conv_engine_fwd(x_in, w, stride, padding, policy: SparsityPolicy,
                 (1, gc))
 
     # --- FP GEMM: patches @ weights ---
-    patches = _im2col(x, r, s, stride, pad4)
-    u, v = patches.shape[1], patches.shape[2]
-    t = n * u * v
-    pm = patches.reshape(t, r * s * c)
+    with stats.lifecycle_scope("layout", "fp"):
+        patches = _im2col(x, r, s, stride, pad4)
+        u, v = patches.shape[1], patches.shape[2]
+        t = n * u * v
+        pm = patches.reshape(t, r * s * c)
     want_a_mask = (policy.use_input_sparsity_fp
                    and policy.kernel_impl == "pallas"
                    and st.bitmap is not None)
@@ -293,8 +302,9 @@ def _conv_engine_fwd(x_in, w, stride, padding, policy: SparsityPolicy,
             bm, bk, bn = policy.block
             a_mask = _patch_bitmap(st, (n, h, wd, c), r, s, stride, pad4) \
                 .mask_for((bm, bk))
-        y = _mm(pm, w.reshape(r * s * c, m), None, a_mask, None, policy,
-                x_in.dtype)
+        with stats.lifecycle_scope("layout", "fp"):
+            w2 = w.reshape(r * s * c, m)
+        y = _mm(pm, w2, None, a_mask, None, policy, x_in.dtype)
     else:
         cg, mg = c // groups, m // groups
         gc = st.gran[1] if st.gran else 1
@@ -306,10 +316,15 @@ def _conv_engine_fwd(x_in, w, stride, padding, policy: SparsityPolicy,
             pb = _patch_bitmap(st, (n, h, wd, c), r, s, stride, pad4)
             pbg = _group_patches(pb.bitmap, r * s, groups)
             a_mask = coarsen_bitmap(pbg, (1, gc), (blk[0], blk[1]))
-        yg = _mm(_group_patches(pm, r * s, groups), _group_weights(w, groups),
-                 None, a_mask, None, policy, x_in.dtype, spec=spec)
-        y = _ungroup_cols(yg)
-    return y.reshape(n, u, v, m), (st, w)
+        with stats.lifecycle_scope("layout", "fp"):
+            pmg = _group_patches(pm, r * s, groups)
+            wg = _group_weights(w, groups)
+        yg = _mm(pmg, wg, None, a_mask, None, policy, x_in.dtype, spec=spec)
+        with stats.lifecycle_scope("layout", "fp"):
+            y = _ungroup_cols(yg)
+    with stats.lifecycle_scope("layout", "fp"):
+        y = y.reshape(n, u, v, m)
+    return y, (st, w)
 
 
 def _conv_engine_bwd(stride, padding, policy: SparsityPolicy,
@@ -319,16 +334,17 @@ def _conv_engine_bwd(stride, padding, policy: SparsityPolicy,
     r, s, _, m = w.shape
     u, v = dy.shape[1], dy.shape[2]
     bm, bk, bn = policy.block
-    if fused_relu:
-        x_pre = st.data
-        relu_mask = (x_pre > 0)
-        x = jnp.where(relu_mask, x_pre, jnp.zeros((), x_pre.dtype))
-        out_dtype = x_pre.dtype
-    else:
-        x = st.data
-        relu_mask = None
-        out_dtype = x.dtype
-    dy32 = dy.astype(jnp.float32)
+    with stats.lifecycle_scope("layout", "bp"):
+        if fused_relu:
+            x_pre = st.data
+            relu_mask = (x_pre > 0)
+            x = jnp.where(relu_mask, x_pre, jnp.zeros((), x_pre.dtype))
+            out_dtype = x_pre.dtype
+        else:
+            x = st.data
+            relu_mask = None
+            out_dtype = x.dtype
+        dy32 = dy.astype(jnp.float32)
     st_dy = _grad_sparse_tensor(dy, dy32, policy, m, groups)
     t = n * u * v
     cg, mg = c // groups, m // groups
@@ -340,16 +356,17 @@ def _conv_engine_bwd(stride, padding, policy: SparsityPolicy,
     # the (N·H·W, C) GEMM. ----
     plh = _pad_amounts(h, r, stride, padding)
     plw = _pad_amounts(wd, s, stride, padding)
-    dyd = _dilate_hw(dy32, stride)
-    hd, wdd = dyd.shape[1], dyd.shape[2]
-    # output spatial size must equal (h, wd):  pad_lo = r-1-fwd_pad_lo
-    pg_h_lo = r - 1 - plh[0]
-    pg_h_hi = h - (hd + pg_h_lo - r + 1)
-    pg_w_lo = s - 1 - plw[0]
-    pg_w_hi = wd - (wdd + pg_w_lo - s + 1)
-    gpad4 = (pg_h_lo, pg_h_hi, pg_w_lo, pg_w_hi)
-    gpatches = _im2col(dyd, r, s, 1, gpad4)
-    gm2 = gpatches.reshape(n * h * wd, r * s * m)
+    with stats.lifecycle_scope("layout", "bp"):
+        dyd = _dilate_hw(dy32, stride)
+        hd, wdd = dyd.shape[1], dyd.shape[2]
+        # output spatial size must equal (h, wd):  pad_lo = r-1-fwd_pad_lo
+        pg_h_lo = r - 1 - plh[0]
+        pg_h_hi = h - (hd + pg_h_lo - r + 1)
+        pg_w_lo = s - 1 - plw[0]
+        pg_w_hi = wd - (wdd + pg_w_lo - s + 1)
+        gpad4 = (pg_h_lo, pg_h_hi, pg_w_lo, pg_w_hi)
+        gpatches = _im2col(dyd, r, s, 1, gpad4)
+        gm2 = gpatches.reshape(n * h * wd, r * s * m)
     # out_mask: the forward ReLU bitmap, re-tiled (footprint(σ') ==
     # footprint(relu) — paper §3.2).  Zero recompute.  Plain convs have no
     # σ' ⇒ no output sparsity (Fig. 11 discussion).
@@ -363,8 +380,9 @@ def _conv_engine_bwd(stride, padding, policy: SparsityPolicy,
             gfb4 = st_dy.bitmap.reshape(n, u, v, m // gcg)
             gpb = _im2col(_dilate_hw(gfb4, stride), r, s, 1, gpad4)
             gpb2 = gpb.reshape(n * h * wd, -1)
-    mask2d = relu_mask.reshape(n * h * wd, c).astype(jnp.float32) \
-        if fused_relu else None
+    with stats.lifecycle_scope("layout", "bp"):
+        mask2d = relu_mask.reshape(n * h * wd, c).astype(jnp.float32) \
+            if fused_relu else None
 
     # This dX GEMM produces the layer BELOW's dy: its writeback epilogue
     # emits that dy's fine bitmap (per-pixel rows, channel granularity of
@@ -373,17 +391,21 @@ def _conv_engine_bwd(stride, padding, policy: SparsityPolicy,
         if _needs_grad_bitmap(policy) else None
 
     if groups == 1:
-        wt = jnp.flip(w, axis=(0, 1)).transpose(0, 1, 3, 2) \
-            .reshape(r * s * m, c)
+        with stats.lifecycle_scope("layout", "bp"):
+            wt = jnp.flip(w, axis=(0, 1)).transpose(0, 1, 3, 2) \
+                .reshape(r * s * m, c)
         out_mask = st.mask_for((bm, bn)) if use_out else None
         g_mask = None
         if gpb2 is not None:
             g_mask = coarsen_bitmap(gpb2, (1, gcg), (bm, bk))
-        res_dx = _mm(gm2, wt.astype(jnp.float32), out_mask, g_mask, None,
+        with stats.lifecycle_scope("layout", "bp"):
+            wt = wt.astype(jnp.float32)
+        res_dx = _mm(gm2, wt, out_mask, g_mask, None,
                      policy, out_dtype, epilogue=mask2d,
                      emit_gran=None if emit_gc is None else (1, emit_gc))
         dx2, dx_bits = res_dx if emit_gc is not None else (res_dx, None)
-        dx = dx2.reshape(n, h, wd, c)
+        with stats.lifecycle_scope("layout", "bp"):
+            dx = dx2.reshape(n, h, wd, c)
         if emit_gc is not None:
             register_grad_bitmap(dx, dx_bits, (1, emit_gc))
     else:
@@ -399,14 +421,17 @@ def _conv_engine_bwd(stride, padding, policy: SparsityPolicy,
         if gpb2 is not None and r * s * mg >= policy.grouped_sparsity_min_k:
             g_mask = coarsen_bitmap(_group_patches(gpb2, r * s, groups),
                                     (1, gcg), (blk[0], blk[1]))
-        epi = _group_cols(mask2d, groups) if mask2d is not None else None
-        res_dx = _mm(_group_patches(gm2, r * s, groups),
-                     _group_weights_bwd(w, groups).astype(jnp.float32),
-                     out_mask, g_mask, None, policy, out_dtype,
+        with stats.lifecycle_scope("layout", "bp"):
+            epi = _group_cols(mask2d, groups) if mask2d is not None \
+                else None
+            gmg = _group_patches(gm2, r * s, groups)
+            wbg = _group_weights_bwd(w, groups).astype(jnp.float32)
+        res_dx = _mm(gmg, wbg, out_mask, g_mask, None, policy, out_dtype,
                      epilogue=epi, spec=spec,
                      emit_gran=None if emit_gc is None else (1, emit_gc))
         dxg, dxg_bits = res_dx if emit_gc is not None else (res_dx, None)
-        dx = _ungroup_cols(dxg).reshape(n, h, wd, c)
+        with stats.lifecycle_scope("layout", "bp"):
+            dx = _ungroup_cols(dxg).reshape(n, h, wd, c)
         if emit_gc is not None and dxg_bits is not None:
             # Per-group bits columns regroup to the full channel axis the
             # same way the data does (cells nest inside groups: gc | C/G).
@@ -414,9 +439,10 @@ def _conv_engine_bwd(stride, padding, policy: SparsityPolicy,
 
     # ---- dW = patches(x)ᵀ @ dy — WG stage, input sparsity both sides ----
     pad4 = (plh[0], plh[1], plw[0], plw[1])
-    patches = _im2col(x, r, s, stride, pad4)
-    pm = patches.reshape(t, r * s * c).astype(jnp.float32)
-    dym = dy32.reshape(t, m)
+    with stats.lifecycle_scope("layout", "wg"):
+        patches = _im2col(x, r, s, stride, pad4)
+        pm = patches.reshape(t, r * s * c).astype(jnp.float32)
+        dym = dy32.reshape(t, m)
     want_pt_mask = _needs_grad_bitmap(policy) and st.bitmap is not None
     if groups == 1:
         pt_mask = None
@@ -425,8 +451,11 @@ def _conv_engine_bwd(stride, padding, policy: SparsityPolicy,
             pt_mask = _patch_bitmap(st, (n, h, wd, c), r, s, stride, pad4) \
                 .t_mask_for((bm, bk))
         dym_mask = st_dy.mask_for((bk, bn))
-        dw = _mm(pm.T, dym, None, pt_mask, dym_mask, policy, jnp.float32)
-        dw = dw.reshape(r, s, c, m)
+        with stats.lifecycle_scope("layout", "wg"):
+            pmt = pm.T
+        dw = _mm(pmt, dym, None, pt_mask, dym_mask, policy, jnp.float32)
+        with stats.lifecycle_scope("layout", "wg"):
+            dw = dw.reshape(r, s, c, m)
     else:
         spec = policy.gemm_spec(groups=groups, dims=(r * s * cg, t, mg),
                                 grans=(gc, 1, gcg))
@@ -441,12 +470,17 @@ def _conv_engine_bwd(stride, padding, policy: SparsityPolicy,
         if st_dy.bitmap is not None:
             dym_mask = coarsen_bitmap(_group_cols(st_dy.bitmap, groups),
                                       (1, gcg), (blk[1], blk[2]))
-        dwg = _mm(_group_patches(pm, r * s, groups).transpose(0, 2, 1),
-                  _group_cols(dym, groups), None, pt_mask, dym_mask, policy,
-                  jnp.float32, spec=spec)
+        with stats.lifecycle_scope("layout", "wg"):
+            pmg = _group_patches(pm, r * s, groups).transpose(0, 2, 1)
+            dymg = _group_cols(dym, groups)
+        dwg = _mm(pmg, dymg, None, pt_mask, dym_mask, policy, jnp.float32,
+                  spec=spec)
         # (G, R·S·C//G, M//G) -> (R, S, C//G, M) group-major output channels
-        dw = dwg.transpose(1, 0, 2).reshape(r, s, cg, m)
-    return dx, dw.astype(w.dtype)
+        with stats.lifecycle_scope("layout", "wg"):
+            dw = dwg.transpose(1, 0, 2).reshape(r, s, cg, m)
+    with stats.lifecycle_scope("layout", "wg"):
+        dw = dw.astype(w.dtype)
+    return dx, dw
 
 
 _conv_engine.defvjp(_conv_engine_fwd, _conv_engine_bwd)

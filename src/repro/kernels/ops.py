@@ -324,9 +324,11 @@ def sparse_gemm(
         if spec.groups != 1:
             raise ValueError(
                 f"2-D operands require spec.groups == 1, got {spec.groups}")
-        a3, b3 = a[None], b[None]
-        masks = GemmMasks(*(m if m is None else m[None] for m in masks))
-        mult3 = None if epilogue_mult is None else epilogue_mult[None]
+        with stats.lifecycle_scope("layout", "lift"):
+            a3, b3 = a[None], b[None]
+            masks = GemmMasks(*(m if m is None else m[None]
+                                for m in masks))
+            mult3 = None if epilogue_mult is None else epilogue_mult[None]
     else:
         if a.shape[0] != spec.groups:
             raise ValueError(
@@ -346,9 +348,11 @@ def sparse_gemm(
         res = _dispatch(a3, b3, masks, spec, mult3)
     if spec.emits_bitmap:
         out, bits = res
-        bits = _tamper("gemm:emit_bits", bits)
-        return (out[0], bits[0]) if not grouped_in else (out, bits)
-    return res[0] if not grouped_in else res
+        res = (out, _tamper("gemm:emit_bits", bits))
+    if grouped_in:
+        return res
+    with stats.lifecycle_scope("layout", "lift"):
+        return jax.tree.map(lambda x: x[0], res)
 
 
 def _observe_live_tiles(spec: GemmSpec, a3, b3, masks: GemmMasks) -> None:
@@ -417,12 +421,14 @@ def _dispatch(a, b, masks: GemmMasks, spec: GemmSpec, mult):
 
     ni, nk, nj = grid_shape((m, k, n), spec.block)
     mp, kp, np_ = ni * bm, nk * bk, nj * bn
-    a_p = pad3(a, mp, kp)
-    b_p = pad3(b, kp, np_)
-    mult_p = None if mult is None else pad3(mult.astype(jnp.float32), mp, np_)
-    om = pad_mask3(masks.out, g, ni, nj)
-    am = pad_mask3(masks.a, g, ni, nk)
-    bmask = pad_mask3(masks.b, g, nk, nj)
+    with stats.lifecycle_scope("pad", spec.schedule):
+        a_p = pad3(a, mp, kp)
+        b_p = pad3(b, kp, np_)
+        mult_p = None if mult is None \
+            else pad3(mult.astype(jnp.float32), mp, np_)
+        om = pad_mask3(masks.out, g, ni, nj)
+        am = pad_mask3(masks.a, g, ni, nk)
+        bmask = pad_mask3(masks.b, g, nk, nj)
     itp = _use_interpret(spec.interpret)
 
     def _predicated():
@@ -455,29 +461,30 @@ def _dispatch(a, b, masks: GemmMasks, spec: GemmSpec, mult):
             )
             if emit is not None:
                 compacted, bits_c = compacted
-            # Scatter the queue back to dense tile layout.  Padding steps
-            # carry zero tiles at coords of dead queue slots — we direct
-            # dead slots at (0, 0, 0) via scatter-ADD so they are no-ops.
-            live_slot = jnp.arange(s_cap) < n_active[0]
-            live = live_slot.astype(out_dtype)
-            masked = compacted * live[:, None, None]
-            sg = jnp.where(live_slot, gg, 0)
-            si = jnp.where(live_slot, ii, 0)
-            sj = jnp.where(live_slot, jj, 0)
-            out_tiles = jnp.zeros((g, ni, nj, bm, bn), out_dtype)
-            out_tiles = out_tiles.at[sg, si, sj].add(masked)
-            out_d = out_tiles.transpose(0, 1, 3, 2, 4).reshape(g, mp, np_)
-            if emit is None:
-                return out_d
-            # Emitted bits ride the same steered scatter as their tiles
-            # (dead slots carry zero bits: their accumulator never left 0).
-            er, ec = emit
-            bits_m = bits_c * live_slot.astype(jnp.int32)[:, None, None]
-            bt = jnp.zeros((g, ni, nj, bm // er, bn // ec), jnp.int32)
-            bt = bt.at[sg, si, sj].add(bits_m)
-            bits = bt.transpose(0, 1, 3, 2, 4).reshape(
-                g, mp // er, np_ // ec)
-            return out_d, bits
+            with stats.lifecycle_scope("scatter", spec.schedule):
+                # Scatter the queue back to dense tile layout.  Padding steps
+                # carry zero tiles at coords of dead queue slots — we direct
+                # dead slots at (0, 0, 0) via scatter-ADD so they are no-ops.
+                live_slot = jnp.arange(s_cap) < n_active[0]
+                live = live_slot.astype(out_dtype)
+                masked = compacted * live[:, None, None]
+                sg = jnp.where(live_slot, gg, 0)
+                si = jnp.where(live_slot, ii, 0)
+                sj = jnp.where(live_slot, jj, 0)
+                out_tiles = jnp.zeros((g, ni, nj, bm, bn), out_dtype)
+                out_tiles = out_tiles.at[sg, si, sj].add(masked)
+                out_d = out_tiles.transpose(0, 1, 3, 2, 4).reshape(g, mp, np_)
+                if emit is None:
+                    return out_d
+                # Emitted bits ride the same steered scatter as their tiles
+                # (dead slots carry zero bits: their accumulator never left 0).
+                er, ec = emit
+                bits_m = bits_c * live_slot.astype(jnp.int32)[:, None, None]
+                bt = jnp.zeros((g, ni, nj, bm // er, bn // ec), jnp.int32)
+                bt = bt.at[sg, si, sj].add(bits_m)
+                bits = bt.transpose(0, 1, 3, 2, 4).reshape(
+                    g, mp // er, np_ // ec)
+                return out_d, bits
 
         if s_cap >= g * ni * nj:
             out = _compact()          # queue provably cannot overflow
@@ -498,15 +505,17 @@ def _dispatch(a, b, masks: GemmMasks, spec: GemmSpec, mult):
             out = jax.lax.cond(n_live > s_cap, _predicated, _compact)
     else:
         out = _predicated()
-    if emit is None:
-        return out[:, :m, :n]
-    er, ec = emit
-    out, bits = out
-    # Padding tiles are dead (zero accumulators), so the padded bitmap
-    # rows/cols are exactly 0 — unpadding to the data's covering grid is
-    # exact, matching what a fresh scan of the unpadded output would give.
-    return out[:, :m, :n], bits[:, :ceil_to(m, er) // er,
-                                :ceil_to(n, ec) // ec]
+    with stats.lifecycle_scope("scatter", spec.schedule):
+        if emit is None:
+            return out[:, :m, :n]
+        er, ec = emit
+        out, bits = out
+        # Padding tiles are dead (zero accumulators), so the padded bitmap
+        # rows/cols are exactly 0 — unpadding to the data's covering grid
+        # is exact, matching what a fresh scan of the unpadded output
+        # would give.
+        return out[:, :m, :n], bits[:, :ceil_to(m, er) // er,
+                                    :ceil_to(n, ec) // ec]
 
 
 # ---------------------------------------------------------------------------

@@ -39,30 +39,16 @@ Key families (normalized):
                                                   quarantine_clamp,
                                                   ckpt_fallback,
                                                   verdict:<v>
-
-Legacy key heads from the pre-redesign orchestrators ("mm", "gmm",
-"grouped_mm") are aliased onto the normalized ``gemm`` family at record
-time, so old recorders and new readers agree.
 """
 from __future__ import annotations
 
 import collections
-import contextlib
 import itertools
 from typing import Dict, Optional, Tuple
 
 import jax
 
 _COUNTS: "collections.Counter[str]" = collections.Counter()
-
-# Pre-redesign per-GEMM key heads → the normalized family.  Aliasing happens
-# at record() time so queries never need to know the legacy spellings.
-_KEY_ALIASES = {"mm": "gemm", "gmm": "gemm", "grouped_mm": "gemm"}
-
-
-def _normalize(kind: str) -> str:
-    head, sep, rest = kind.partition(":")
-    return _KEY_ALIASES.get(head, head) + sep + rest
 
 
 def record(kind: str) -> None:
@@ -73,7 +59,7 @@ def record(kind: str) -> None:
     ∈ {prefix_sum, argsort} — so ``queue_builds("argsort")`` audits that the
     default compact path never sorts (the PR-2 contract); for gemm it is
     ``<schedule>:<g>`` — the dispatcher's normalized launch key."""
-    _COUNTS[_normalize(kind)] += 1
+    _COUNTS[kind] += 1
 
 
 def reset() -> None:
@@ -108,9 +94,8 @@ def gemm_launches(schedule: str = "", groups: Optional[int] = None) -> int:
     for k, v in _COUNTS.items():
         if not k.startswith("gemm:"):
             continue
-        # Aliased legacy recorders may lack the :<g> suffix ("mm:compact"
-        # → "gemm:compact"); treat the group field as unknown rather than
-        # crashing the reader.
+        # A key without the :<g> suffix has an unknown group field; the
+        # group filter skips it rather than crashing the reader.
         _, _, tail = k.partition(":")
         sched, _, g = tail.partition(":")
         if schedule and sched != schedule:
@@ -157,19 +142,9 @@ def record_at_runtime(kind: str, flag) -> None:
 
     def _cb(v):
         if float(v) != 0.0:
-            _COUNTS[_normalize(kind)] += 1
+            _COUNTS[kind] += 1
 
     _jax.debug.callback(_cb, flag)
-
-
-@contextlib.contextmanager
-def counting():
-    """Scoped counter: resets on entry, yields the live ``counts`` getter."""
-    reset()
-    try:
-        yield counts
-    finally:
-        pass
 
 
 # ---------------------------------------------------------------------------
@@ -224,7 +199,7 @@ def live_tile_keys() -> list:
 
 
 # ---------------------------------------------------------------------------
-# Lifecycle scopes — the static (jaxpr-visible) twin of the counters above
+# Scopes — the static (jaxpr-visible) twin of the counters above
 # ---------------------------------------------------------------------------
 #
 # Counters audit a trace that RAN.  ``analysis/jaxpr_audit.py`` proves the
@@ -238,27 +213,65 @@ def live_tile_keys() -> list:
 #
 #     repro:<kind>[:<detail>]:<seq>
 #
-# kind ∈ {encode, scan, derive, queue, gemm, fallback} — mirroring the
-# counter families.  <seq> is a process-global instance number so two scans
-# of the SAME tensor get DISTINCT region identities (that duplication is
-# exactly the violation the audit must be able to see).  Model layers use
-# the separate ``layer:<name>`` grammar (``layer_scope``) purely for keying
-# violation reports by layer.
+# Lifecycle kinds ∈ {encode, scan, derive, queue, gemm, fallback,
+# collective} — mirroring the counter families.  <seq> is a process-global
+# instance number so two scans of the SAME tensor get DISTINCT region
+# identities (that duplication is exactly the violation the audit must be
+# able to see).
+#
+# Attribution kinds name where the rest of a step's device time goes; they
+# are not bitmap events, and the audit looks through them:
+#
+#     layout:<fp|bp|wg>          the conv engine's data-side operand and
+#                                result re-layout per stage (im2col, dy
+#                                dilation, weight flip/transpose, σ′
+#                                multiplier, pm.T, group and output
+#                                reshapes); never encloses a lifecycle scope
+#     layout:lift                ``sparse_gemm``'s lift of 2-D operands to
+#                                the grouped engine's G=1, and the unlift
+#                                of its result, outside the gemm scope
+#     pool:<max|avg|gap>         a pool and the ReLU applied just before it
+#     norm:bn                    BatchNorm
+#     merge:<add|concat>         a branch's merge and the ReLU on its entry
+#     pad:<schedule>             inside a gemm scope: operand, mask and
+#                                epilogue-multiplier padding
+#     scatter:<schedule>         inside a gemm scope: the compact queue's
+#                                scatter back to dense tiles, and the unpad
+#
+# Scope names reach the compiled HLO's ``op_name`` metadata, where the chip
+# benchmark's trace reduction reads them (benchmarks/chip/metrics/): XLA ops
+# that autodiff derives from a scope keep it as ``transpose(jvp(tag))``.
+# Attribution tags draw <seq> from a count of their own, so adding them
+# leaves every lifecycle tag — and the Pallas kernels named after one —
+# as it was.  Model layers use the separate ``layer:<name>`` grammar
+# (``layer_scope``) for keying violation reports by layer.
+
+LIFECYCLE_KINDS = ("encode", "scan", "derive", "queue", "gemm", "fallback",
+                   "collective")
+ATTRIBUTION_KINDS = ("layout", "pool", "norm", "merge", "pad", "scatter")
 
 _SCOPE_SEQ = itertools.count()
+_ATTRIBUTION_SEQ = itertools.count()
 
 
 def lifecycle_scope(kind: str, detail: str = ""):
-    """A ``jax.named_scope`` carrying one bitmap-lifecycle event tag.
+    """A ``jax.named_scope`` carrying one ``repro:`` tag.
 
-    Wrap the ops that *compute* sparsity metadata (kind="encode"/"scan"),
-    *derive* it (kind="derive"), build work queues (kind="queue"), consume
-    it in a GEMM dispatch (kind="gemm"), or escape the engine entirely
-    (kind="fallback").  ``detail`` refines the kind (e.g. the scan target,
-    the gemm ``<schedule>:<g>`` launch key).
+    Lifecycle kinds wrap the ops that *compute* sparsity metadata
+    (kind="encode"/"scan"), *derive* it (kind="derive"), build work queues
+    (kind="queue"), consume it in a GEMM dispatch (kind="gemm"), escape the
+    engine entirely (kind="fallback") or move it across shards
+    (kind="collective").  Attribution kinds (``ATTRIBUTION_KINDS``) wrap
+    the device work around them.  ``detail`` refines the kind (e.g. the
+    scan target, the gemm ``<schedule>:<g>`` launch key, the conv stage).
     """
-    parts = ["repro", kind] + ([detail] if detail else []) \
-        + [str(next(_SCOPE_SEQ))]
+    if kind in LIFECYCLE_KINDS:
+        seq = next(_SCOPE_SEQ)
+    elif kind in ATTRIBUTION_KINDS:
+        seq = next(_ATTRIBUTION_SEQ)
+    else:
+        raise ValueError(f"unknown scope kind {kind!r}")
+    parts = ["repro", kind] + ([detail] if detail else []) + [str(seq)]
     return jax.named_scope(":".join(parts))
 
 

@@ -88,9 +88,10 @@ def conv_init(key, node: ConvNode, in_ch: int, dtype=jnp.float32) -> Params:
 
 
 def batchnorm(x: jnp.ndarray, scale, bias, eps=1e-5) -> jnp.ndarray:
-    mu = jnp.mean(x, axis=(0, 1, 2), keepdims=True)
-    var = jnp.var(x, axis=(0, 1, 2), keepdims=True)
-    return (x - mu) * jax.lax.rsqrt(var + eps) * scale + bias
+    with stats.lifecycle_scope("norm", "bn"):
+        mu = jnp.mean(x, axis=(0, 1, 2), keepdims=True)
+        var = jnp.var(x, axis=(0, 1, 2), keepdims=True)
+        return (x - mu) * jax.lax.rsqrt(var + eps) * scale + bias
 
 
 def apply_conv(p: Params, x_pre: jnp.ndarray, node: ConvNode,
@@ -282,22 +283,27 @@ class CNNModel:
                         capture[node.name] = jnp.maximum(x, 0) \
                             if node.relu_after else x
                 elif isinstance(node, PoolNode):
-                    if input_is_relu:
-                        x = jnp.maximum(x, 0)
-                        input_is_relu = False
-                    x = apply_pool(x, node)
+                    with stats.lifecycle_scope("pool", node.kind):
+                        if input_is_relu:
+                            x = jnp.maximum(x, 0)
+                            input_is_relu = False
+                        x = apply_pool(x, node)
                 elif isinstance(node, Branch):
                     if input_is_relu:
-                        x = jnp.maximum(x, 0)
+                        with stats.lifecycle_scope("merge", node.merge):
+                            x = jnp.maximum(x, 0)
                         input_is_relu = False
                     outs = []
                     for path in node.paths:
                         y, y_relu = run(path, x, False)
                         if y_relu:
-                            y = jnp.maximum(y, 0)
+                            with stats.lifecycle_scope("merge", node.merge):
+                                y = jnp.maximum(y, 0)
                         outs.append(y)
-                    x = jnp.concatenate(outs, -1) if node.merge == "concat" \
-                        else functools.reduce(jnp.add, outs)
+                    with stats.lifecycle_scope("merge", node.merge):
+                        x = jnp.concatenate(outs, -1) \
+                            if node.merge == "concat" \
+                            else functools.reduce(jnp.add, outs)
                     if node.merge == "add":
                         # post-merge ReLU (ResNet): re-enters pre-act domain
                         if capture is not None:
@@ -306,9 +312,10 @@ class CNNModel:
             return x, input_is_relu
 
         x, is_relu = run(self.layers, images, False)
-        if is_relu:
-            x = jnp.maximum(x, 0)
-        x = jnp.mean(x, axis=(1, 2))             # global average pool
+        with stats.lifecycle_scope("pool", "gap"):
+            if is_relu:
+                x = jnp.maximum(x, 0)
+            x = jnp.mean(x, axis=(1, 2))         # global average pool
         # Head GEMM through the sparse-aware unit: the pooled feature (post
         # global-mean, so typically dense) contributes no FP skipping, but
         # its bitmap is computed once and threaded to the WG stage, and the

@@ -322,9 +322,6 @@ def test_dispatch_records_normalized_stats_keys():
         and c["gemm:dense:1"] == 1, c
     assert stats.gemm_launches() == 3
     assert stats.gemm_launches(schedule="compact") == 1
-    # legacy key heads alias onto the normalized family
-    stats.record("mm:predicated:1")
-    assert stats.counts()["gemm:predicated:1"] == 2
 
 
 def test_launch_geometry_matches_dispatch_contract():

@@ -29,21 +29,67 @@ def test_chip_smoke_refuses_without_a_tpu():
     assert "needs a TPU" in out.stderr
 
 
-def test_compile_cache_dir_follows_env(monkeypatch):
+_CACHE_FLAGS = ("jax_compilation_cache_dir",
+                "jax_compilation_cache_include_metadata_in_key",
+                "jax_hlo_source_file_canonicalization_regex",
+                "jax_persistent_cache_min_compile_time_secs",
+                "jax_persistent_cache_min_entry_size_bytes")
+
+
+@pytest.fixture
+def cache_flags():
+    """Restores the persistent cache's flags, and its state, after a test
+    that sets them."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = {k: getattr(jax.config, k) for k in _CACHE_FLAGS}
+    try:
+        yield
+    finally:
+        for k, v in prev.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+
+
+def test_compile_cache_dir_follows_env(monkeypatch, cache_flags):
     """The entry points' cache helper leaves a JAX_COMPILATION_CACHE_DIR
     to JAX and otherwise uses the repository's fixed .jax_cache."""
     from repro.launch import cache
     prev = jax.config.jax_compilation_cache_dir
-    try:
-        monkeypatch.setenv(cache.ENV_VAR, "/elsewhere/cache")
-        assert cache.use_compile_cache() == "/elsewhere/cache"
-        assert jax.config.jax_compilation_cache_dir == prev
-        monkeypatch.delenv(cache.ENV_VAR)
-        want = os.path.join(os.path.abspath(REPO), ".jax_cache")
-        assert cache.use_compile_cache() == want
-        assert jax.config.jax_compilation_cache_dir == want
-    finally:
-        jax.config.update("jax_compilation_cache_dir", prev)
+    monkeypatch.setenv(cache.ENV_VAR, "/elsewhere/cache")
+    assert cache.use_compile_cache() == "/elsewhere/cache"
+    assert jax.config.jax_compilation_cache_dir == prev
+    monkeypatch.delenv(cache.ENV_VAR)
+    want = os.path.join(os.path.abspath(REPO), ".jax_cache")
+    assert cache.use_compile_cache() == want
+    assert jax.config.jax_compilation_cache_dir == want
+
+
+def test_compile_cache_keeps_each_programs_scope_names(
+        monkeypatch, tmp_path, cache_flags):
+    """Two programs that differ only in a scope name do not share a cache
+    entry once the helper has run: each compiled program reports its own
+    op_name, which a profile of it shows and the chip benchmark reads."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from repro.launch import cache
+    monkeypatch.setenv(cache.ENV_VAR, str(tmp_path))
+    cache.use_compile_cache()
+    # What JAX does with the variable when it starts.
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    cc.reset_cache()
+
+    def scoped(name):
+        def f(x):
+            with jax.named_scope(name):
+                return jnp.sin(x) * 2
+        return f
+
+    texts = [jax.jit(scoped(name)).lower(jnp.ones(4)).compile().as_text()
+             for name in ("scope_first", "scope_second")]
+    assert "scope_first" in texts[0]
+    assert "scope_second" in texts[1] and "scope_first" not in texts[1]
+    assert len([p for p in os.listdir(tmp_path) if "jit_f" in p]) == 2
 
 
 def test_lm_training_learns():
