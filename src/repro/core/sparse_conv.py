@@ -4,6 +4,15 @@ The paper's accelerator executes CONV as GEMM over the receptive field
 (K = C·R·S — its "synapse blocking at 1024" is K-blocking, §4.4).  We do the
 same: im2col the operand, run the block-sparse GEMM kernels, fold back.
 
+For ``groups == 1`` every stage's patch operand is written once, in the
+layout the kernel reads: ``_im2col_tiled`` concatenates the shifted slices
+and the zero columns up to Kp (K rounded up to the tile) along the channel
+axis into a row-major (T, Kp) matrix (a patch row within one lane tile is
+stacked and padded instead), and the weight matrix gets Kp − K zero rows,
+so the dispatcher neither re-lays-out nor pads it.  WG computes
+dWᵀ = dyᵀ · P on the same row-major P as FP, transposing dy (one
+activation) and the small (M, Kp) result instead of the patch matrix.
+
 ONE engine, four public faces.  ``_conv_engine_fwd``/``_conv_engine_bwd``
 is a single parameterized custom-VJP pair taking ``(fused_relu, groups)``;
 every conv flavour is a thin wrapper over it:
@@ -30,14 +39,16 @@ other mask is then *derived* from it without rescanning tensor-sized data:
 
   * the backward out_mask is the same bitmap re-tiled to (bm, bn) — the
     paper's FP/BP footprint identity;
-  * patch (im2col) operand masks — FP a_mask and the WG Xᵀ mask — come from
+  * patch (im2col) operand masks — FP a_mask and the WG P mask — come from
     running ``_im2col`` on the BITMAP itself (a gather over an array C/gc×
     smaller than the activation), then coarsening.  This is exact, because
     an im2col'd any-nonzero cell equals the any-nonzero of the im2col'd
-    data (same gather, zero padding on both sides);
+    data (same gather, zero padding on both sides); the Kp − K zero columns
+    of the data are dead tiles or a tile's zero tail, so the masks of the
+    unpadded bitmap describe the padded operand;
   * the incoming gradient is scanned at most once per step; its dilated/
-    im2col'd mask (dX GEMM) and its (bk, bn) re-tiling (dW GEMM) are both
-    derived from that single fine bitmap.
+    im2col'd mask (dX GEMM) and its transposed (bm, bk) re-tiling (the dyᵀ
+    operand of the dW GEMM) are both derived from that single fine bitmap.
 
 Grouped convs reuse the SAME derivations: the channel granularity divides
 C//G (see ``conv_channel_granularity``), so per-group masks are pure
@@ -64,7 +75,7 @@ import jax.numpy as jnp
 
 from repro.kernels import ops as kops
 from repro.kernels import stats
-from repro.kernels.shapes import block_bitmap as _bitmap_padded
+from repro.kernels.shapes import block_bitmap as _bitmap_padded, ceil_to
 from .policy import SparsityPolicy
 from .sparse_linear import _mm, _needs_act_bitmap, _needs_grad_bitmap
 from .sparse_tensor import (
@@ -75,6 +86,11 @@ from .sparse_tensor import (
     register_grad_bitmap,
     scan_bitmap,
 )
+
+
+# Width of a TPU vector register's lane axis: the minor tile edge of every
+# f32 array in HBM.
+_LANES = 128
 
 
 def _pad_amounts(h: int, r: int, stride: int, padding: str) -> Tuple[int, int]:
@@ -90,27 +106,69 @@ def conv_out_size(h: int, r: int, stride: int, padding: str) -> int:
     return (h + lo + hi - r) // stride + 1
 
 
-def _im2col(x: jnp.ndarray, r: int, s: int, stride: int,
-            pad: Tuple[int, int, int, int]) -> jnp.ndarray:
-    """x: (N,H,W,C) -> (N, U, V, R*S*C) patches, (r, s, c)-ordered."""
+def _taps(x: jnp.ndarray, r: int, s: int, stride: int,
+          pad: Tuple[int, int, int, int]):
+    """The R·S shifted, strided (N, U, V, C) views of x that an im2col
+    lays side by side, in (r, s) order."""
     n, h, w, c = x.shape
     plo_h, phi_h, plo_w, phi_w = pad
     xp = jnp.pad(x, ((0, 0), (plo_h, phi_h), (plo_w, phi_w), (0, 0)))
     hp, wp = h + plo_h + phi_h, w + plo_w + phi_w
     u = (hp - r) // stride + 1
     v = (wp - s) // stride + 1
-    cols = []
-    for dr in range(r):
-        for ds in range(s):
-            cols.append(
-                jax.lax.slice(
-                    xp, (0, dr, ds, 0),
-                    (n, dr + (u - 1) * stride + 1, ds + (v - 1) * stride + 1, c),
-                    (1, stride, stride, 1),
-                )
-            )
+    return [
+        jax.lax.slice(
+            xp, (0, dr, ds, 0),
+            (n, dr + (u - 1) * stride + 1, ds + (v - 1) * stride + 1, c),
+            (1, stride, stride, 1),
+        )
+        for dr in range(r) for ds in range(s)
+    ]
+
+
+def _im2col(x: jnp.ndarray, r: int, s: int, stride: int,
+            pad: Tuple[int, int, int, int]) -> jnp.ndarray:
+    """x: (N,H,W,C) -> (N, U, V, R*S*C) patches, (r, s, c)-ordered.
+
+    The stacked form serves the bitmap derivations and patch rows no wider
+    than one lane tile, for which XLA compiles it to cheaper code than
+    ``_im2col_tiled``'s concatenate (PERF.md), and the grouped
+    engine, which regroups its columns by tap."""
+    cols = _taps(x, r, s, stride, pad)
+    n, u, v, c = cols[0].shape
     patches = jnp.stack(cols, axis=3)          # (N,U,V,R*S,C)
     return patches.reshape(n, u, v, r * s * c)
+
+
+def _im2col_tiled(x: jnp.ndarray, r: int, s: int, stride: int,
+                  pad: Tuple[int, int, int, int], kp: int) -> jnp.ndarray:
+    """x: (N,H,W,C) -> (N·U·V, Kp) patch matrix in the layout the GEMM
+    kernel reads: the (r, s, c)-ordered R·S·C columns of ``_im2col``, then
+    Kp − R·S·C zero columns, written by ONE concatenate along the channel
+    (lane) axis.  The (N, U, V, Kp) result is row-major, so the reshape to
+    rows is a bitcast wherever V is a multiple of 8, and ``sparse_gemm``
+    finds K already a whole number of tiles: no stack, relayout copy or K
+    pad of the patch-sized array.
+
+    A patch row no wider than one lane tile (the RGB input layer, K = 27)
+    would be concatenated from pieces a few lanes wide, which XLA writes
+    slowly; it takes ``_im2col``'s stacked form and one pad to Kp."""
+    k = r * s * x.shape[3]
+    if k <= _LANES:
+        pm = _im2col(x, r, s, stride, pad).reshape(-1, k)
+        return jnp.pad(pm, ((0, 0), (0, kp - k)))
+    cols = _taps(x, r, s, stride, pad)
+    n, u, v, c = cols[0].shape
+    if kp > k:
+        cols.append(jnp.zeros((n, u, v, kp - k), x.dtype))
+    return jnp.concatenate(cols, axis=3).reshape(n * u * v, kp)
+
+
+def _tiled_k(k: int, edge: int, policy: SparsityPolicy) -> int:
+    """Length of a GEMM axis of extent ``k`` as the kernel reads it: whole
+    tiles of ``edge`` on the Pallas path; the dense schedule tiles nothing
+    and takes ``k`` as it is."""
+    return ceil_to(k, edge) if policy.kernel_impl == "pallas" else k
 
 
 def _dilate_hw(x: jnp.ndarray, stride: int) -> jnp.ndarray:
@@ -224,9 +282,10 @@ def _grad_sparse_tensor(dy, dy32: jnp.ndarray, policy: SparsityPolicy,
     bm, bk, bn = policy.block
     # The conv derivations need per-pixel rows (the bitmap is reshaped to
     # the (N, U, V, M/gc) spatial view), channel cells nesting inside
-    # groups, and a channel granularity every derived mask edge divides.
+    # groups, and a channel granularity every derived mask edge divides
+    # (bm: the dyᵀ operand of the groups == 1 WG GEMM).
     if (gr != 1 or m % gcg or (m // gcg) % groups
-            or bk % gcg or bn % gcg):
+            or bm % gcg or bk % gcg or bn % gcg):
         return SparseTensor(dy32, None, None)
     return SparseTensor(dy32, fb, (1, gcg))
 
@@ -288,24 +347,28 @@ def _conv_engine_fwd(x_in, w, stride, padding, policy: SparsityPolicy,
                 (1, gc))
 
     # --- FP GEMM: patches @ weights ---
-    with stats.lifecycle_scope("layout", "fp"):
-        patches = _im2col(x, r, s, stride, pad4)
-        u, v = patches.shape[1], patches.shape[2]
-        t = n * u * v
-        pm = patches.reshape(t, r * s * c)
+    u = conv_out_size(h, r, stride, padding)
+    v = conv_out_size(wd, s, stride, padding)
+    t = n * u * v
     want_a_mask = (policy.use_input_sparsity_fp
                    and policy.kernel_impl == "pallas"
                    and st.bitmap is not None)
     if groups == 1:
+        bm, bk, bn = policy.block
+        k = r * s * c
+        kp = _tiled_k(k, bk, policy)
+        with stats.lifecycle_scope("layout", "fp"):
+            pm = _im2col_tiled(x, r, s, stride, pad4, kp)
         a_mask = None
         if want_a_mask:
-            bm, bk, bn = policy.block
             a_mask = _patch_bitmap(st, (n, h, wd, c), r, s, stride, pad4) \
                 .mask_for((bm, bk))
         with stats.lifecycle_scope("layout", "fp"):
-            w2 = w.reshape(r * s * c, m)
+            w2 = jnp.pad(w.reshape(k, m), ((0, kp - k), (0, 0)))
         y = _mm(pm, w2, None, a_mask, None, policy, x_in.dtype)
     else:
+        with stats.lifecycle_scope("layout", "fp"):
+            pm = _im2col(x, r, s, stride, pad4).reshape(t, r * s * c)
         cg, mg = c // groups, m // groups
         gc = st.gran[1] if st.gran else 1
         spec = policy.gemm_spec(groups=groups, dims=(t, r * s * cg, mg),
@@ -365,8 +428,6 @@ def _conv_engine_bwd(stride, padding, policy: SparsityPolicy,
         pg_w_lo = s - 1 - plw[0]
         pg_w_hi = wd - (wdd + pg_w_lo - s + 1)
         gpad4 = (pg_h_lo, pg_h_hi, pg_w_lo, pg_w_hi)
-        gpatches = _im2col(dyd, r, s, 1, gpad4)
-        gm2 = gpatches.reshape(n * h * wd, r * s * m)
     # out_mask: the forward ReLU bitmap, re-tiled (footprint(σ') ==
     # footprint(relu) — paper §3.2).  Zero recompute.  Plain convs have no
     # σ' ⇒ no output sparsity (Fig. 11 discussion).
@@ -391,15 +452,18 @@ def _conv_engine_bwd(stride, padding, policy: SparsityPolicy,
         if _needs_grad_bitmap(policy) else None
 
     if groups == 1:
+        kg = r * s * m
+        kgp = _tiled_k(kg, bk, policy)
         with stats.lifecycle_scope("layout", "bp"):
+            gm2 = _im2col_tiled(dyd, r, s, 1, gpad4, kgp)
             wt = jnp.flip(w, axis=(0, 1)).transpose(0, 1, 3, 2) \
-                .reshape(r * s * m, c)
+                .reshape(kg, c)
         out_mask = st.mask_for((bm, bn)) if use_out else None
         g_mask = None
         if gpb2 is not None:
             g_mask = coarsen_bitmap(gpb2, (1, gcg), (bm, bk))
         with stats.lifecycle_scope("layout", "bp"):
-            wt = wt.astype(jnp.float32)
+            wt = jnp.pad(wt.astype(jnp.float32), ((0, kgp - kg), (0, 0)))
         res_dx = _mm(gm2, wt, out_mask, g_mask, None,
                      policy, out_dtype, epilogue=mask2d,
                      emit_gran=None if emit_gc is None else (1, emit_gc))
@@ -424,6 +488,7 @@ def _conv_engine_bwd(stride, padding, policy: SparsityPolicy,
         with stats.lifecycle_scope("layout", "bp"):
             epi = _group_cols(mask2d, groups) if mask2d is not None \
                 else None
+            gm2 = _im2col(dyd, r, s, 1, gpad4).reshape(n * h * wd, r * s * m)
             gmg = _group_patches(gm2, r * s, groups)
             wbg = _group_weights_bwd(w, groups).astype(jnp.float32)
         res_dx = _mm(gmg, wbg, out_mask, g_mask, None, policy, out_dtype,
@@ -437,31 +502,37 @@ def _conv_engine_bwd(stride, padding, policy: SparsityPolicy,
             # same way the data does (cells nest inside groups: gc | C/G).
             register_grad_bitmap(dx, _ungroup_cols(dxg_bits), (1, emit_gc))
 
-    # ---- dW = patches(x)ᵀ @ dy — WG stage, input sparsity both sides ----
+    # ---- dW — WG stage, input sparsity both sides ----
     pad4 = (plh[0], plh[1], plw[0], plw[1])
     with stats.lifecycle_scope("layout", "wg"):
-        patches = _im2col(x, r, s, stride, pad4)
-        pm = patches.reshape(t, r * s * c).astype(jnp.float32)
         dym = dy32.reshape(t, m)
-    want_pt_mask = _needs_grad_bitmap(policy) and st.bitmap is not None
+    want_p_mask = _needs_grad_bitmap(policy) and st.bitmap is not None
     if groups == 1:
-        pt_mask = None
-        if want_pt_mask:
-            # Xᵀ patch mask: forward bitmap -> patch bitmap -> block transp.
-            pt_mask = _patch_bitmap(st, (n, h, wd, c), r, s, stride, pad4) \
-                .t_mask_for((bm, bk))
-        dym_mask = st_dy.mask_for((bk, bn))
+        # dWᵀ = dyᵀ · P: P is the row-major (T, Kp) patch matrix, as in FP,
+        # and the transpose falls on dy (one activation's bytes) and on the
+        # small (M, Kp) result, never on the R·S×-larger patch matrix.
+        k = r * s * c
+        kp = _tiled_k(k, bn, policy)
         with stats.lifecycle_scope("layout", "wg"):
-            pmt = pm.T
-        dw = _mm(pmt, dym, None, pt_mask, dym_mask, policy, jnp.float32)
+            pm = _im2col_tiled(x.astype(jnp.float32), r, s, stride, pad4, kp)
+            dyt = dym.T
+        p_mask = None
+        if want_p_mask:
+            p_mask = _patch_bitmap(st, (n, h, wd, c), r, s, stride, pad4) \
+                .mask_for((bk, bn))
+        dyt_mask = st_dy.t_mask_for((bm, bk))
+        dwt = _mm(dyt, pm, None, dyt_mask, p_mask, policy, jnp.float32)
         with stats.lifecycle_scope("layout", "wg"):
-            dw = dw.reshape(r, s, c, m)
+            dw = dwt[:, :k].T.reshape(r, s, c, m)
     else:
+        with stats.lifecycle_scope("layout", "wg"):
+            pm = _im2col(x, r, s, stride, pad4).reshape(t, r * s * c) \
+                .astype(jnp.float32)
         spec = policy.gemm_spec(groups=groups, dims=(r * s * cg, t, mg),
                                 grans=(gc, 1, gcg))
         blk = spec.block
         pt_mask = None
-        if want_pt_mask:
+        if want_p_mask:
             pb = _patch_bitmap(st, (n, h, wd, c), r, s, stride, pad4)
             pbg = _group_patches(pb.bitmap, r * s, groups)
             pt_mask = coarsen_bitmap(pbg.transpose(0, 2, 1), (gc, 1),

@@ -421,6 +421,10 @@ def _dispatch(a, b, masks: GemmMasks, spec: GemmSpec, mult):
 
     ni, nk, nj = grid_shape((m, k, n), spec.block)
     mp, kp, np_ = ni * bm, nk * bk, nj * bn
+    for name, x, tiled in (("a", a, (mp, kp)), ("b", b, (kp, np_)),
+                           ("mult", mult, (mp, np_))):
+        if x is not None and x.shape[1:] != tiled:
+            stats.record(f"pad_operand:{name}")
     with stats.lifecycle_scope("pad", spec.schedule):
         a_p = pad3(a, mp, kp)
         b_p = pad3(b, kp, np_)

@@ -20,6 +20,9 @@ Key families (normalized):
                                                   dispatch (schedule ∈
                                                   {predicated, compact,
                                                   dense}; g = group count)
+  pad_operand:<a|b|mult>                          a tiled dispatch that had
+                                                  to pad that operand up to
+                                                  whole tiles (a copy of it)
   conv:dense_fallback                             escaped-the-engine convs
   fallback:queue_overflow                         compact dispatches whose
                                                   live count exceeded the

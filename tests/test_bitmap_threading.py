@@ -19,6 +19,7 @@ import numpy as np
 import pytest
 
 from repro.core import policy as pol
+from repro.core import sparse_conv
 from repro.core.sparse_conv import (
     _im2col, _pad_amounts, _patch_bitmap, _relu_conv_fwd, conv as sconv,
     relu_conv,
@@ -34,6 +35,11 @@ from repro.kernels import stats
 
 PALLAS = pol.IN_OUT_WR.with_(kernel_impl="pallas", block=(8, 16, 8))
 PALLAS_U = pol.IN_OUT.with_(kernel_impl="pallas", block=(16, 16, 16))
+
+# (channels, block override) beside the C = 5 cases on the policies' own
+# tiles: C = 3 and 64 give a patch K (27, 576) that is not a whole number of
+# bk = 128 tiles, C = 128 one that is.
+WIDE_CHANNELS = [(3, (8, 128, 8)), (64, (8, 128, 8)), (128, (8, 128, 8))]
 
 
 def _rand(shape, key, sparsify=0.5):
@@ -68,15 +74,62 @@ def test_act_matmul_threaded_masks_match_oracle(policy):
         st.t_mask_for((bm, bk)), _bitmap_padded(x.T, bm, bk))
 
 
+def _np_block_any(x, b0, b1):
+    """Any-nonzero (b0, b1) block bitmap of a 2-D host array, ragged edges
+    zero-padded: the fresh-scan oracle, in NumPy so a host callback can run
+    it."""
+    m, n = x.shape
+    mp, np_ = -(-m // b0) * b0, -(-n // b1) * b1
+    xp = np.zeros((mp, np_), x.dtype)
+    xp[:m, :n] = x
+    return (xp.reshape(mp // b0, b0, np_ // b1, b1) != 0).any(axis=(1, 3))
+
+
+def _spy_gemms(monkeypatch, block):
+    """Have every GEMM the conv engine issues check, when it runs, that
+    each operand mask it receives equals a fresh scan of that operand.
+    Returns (traced, verdicts): per call its operand shapes and which masks
+    it had, and per call index the (a_ok, b_ok) found at run time."""
+    bm, bk, bn = block
+    traced, verdicts = [], {}
+    real = sparse_conv._mm
+
+    def spy(a, b, out_mask, a_mask, b_mask, policy, *args, **kw):
+        i = len(traced)
+        traced.append((a.shape, b.shape, a_mask is not None,
+                       b_mask is not None))
+
+        def check(a, b, a_mask, b_mask):
+            verdicts[i] = tuple(
+                m is None or np.array_equal(np.asarray(m) != 0,
+                                            _np_block_any(np.asarray(x),
+                                                          *edges))
+                for m, x, edges in ((a_mask, a, (bm, bk)),
+                                    (b_mask, b, (bk, bn))))
+
+        jax.debug.callback(check, a, b, a_mask, b_mask)
+        return real(a, b, out_mask, a_mask, b_mask, policy, *args, **kw)
+
+    monkeypatch.setattr(sparse_conv, "_mm", spy)
+    return traced, verdicts
+
+
 @pytest.mark.parametrize("stride,padding", [(1, "SAME"), (2, "SAME"),
                                             (1, "VALID"), (2, "VALID")])
-@pytest.mark.parametrize("policy", [PALLAS, PALLAS_U])
-def test_relu_conv_threaded_masks_match_oracle(stride, padding, policy):
+@pytest.mark.parametrize(
+    "policy,c,block",
+    [(PALLAS, 5, None), (PALLAS_U, 5, None)]
+    + [(PALLAS, c, block) for c, block in WIDE_CHANNELS])
+def test_relu_conv_threaded_masks_match_oracle(stride, padding, policy, c,
+                                               block, monkeypatch):
+    if block is not None:
+        policy = policy.with_(block=block)
     bm, bk, bn = policy.block
-    n, h, wd, c = 2, 9, 11, 5
+    n, h, wd = 2, 9, 11
     x_pre = _rand((n, h, wd, c), 2)
     w = _rand((3, 3, c, 7), 3, 0.0)
-    _, (st, _) = _relu_conv_fwd(x_pre, w, stride, padding, policy)
+    st = jax.jit(lambda x, w: _relu_conv_fwd(x, w, stride, padding,
+                                             policy)[1][0])(x_pre, w)
     assert st.bitmap is not None
     x = jnp.maximum(x_pre, 0)
     # out_mask over the (N·H·W, C) σ' footprint
@@ -94,6 +147,24 @@ def test_relu_conv_threaded_masks_match_oracle(stride, padding, policy):
         pb.mask_for((bm, bk)), _bitmap_padded(pm, bm, bk))
     np.testing.assert_array_equal(
         pb.t_mask_for((bm, bk)), _bitmap_padded(pm.T, bm, bk))
+    np.testing.assert_array_equal(
+        pb.mask_for((bk, bn)), _bitmap_padded(pm, bk, bn))
+    # Every operand mask of a two-unit chain's step, the WG stage's dyᵀ
+    # (threaded from the upper unit's dX epilogue) and patch matrix P among
+    # them, equals a fresh scan of the operand the GEMM receives.
+    traced, verdicts = _spy_gemms(monkeypatch, policy.block)
+    w2 = _rand((3, 3, 7, 6), 4, 0.0)
+    grads = jax.jit(jax.grad(
+        lambda x, w, w2: (relu_conv(relu_conv(x, w, stride, padding, policy),
+                                    w2, 1, "SAME", policy) ** 2).sum(),
+        (0, 1, 2)))(x_pre, w, w2)
+    jax.block_until_ready(grads)
+    jax.effects_barrier()
+    assert len(traced) == 6
+    assert verdicts == {i: (True, True) for i in range(6)}
+    # the lower unit's WG: dWᵀ = dyᵀ · P, both operands masked
+    assert traced[-1] == ((7, pm.shape[0]),
+                          (pm.shape[0], -(-9 * c // bn) * bn), True, True)
 
 
 def test_coarsen_bitmap_is_exact_or_reduce():
@@ -138,12 +209,18 @@ def test_act_matmul_grads_exact_after_threading(policy):
 
 @pytest.mark.parametrize("stride,padding", [(1, "SAME"), (2, "SAME"),
                                             (1, "VALID"), (2, "VALID")])
-@pytest.mark.parametrize("policy", [PALLAS, PALLAS_U,
-                                    PALLAS_U.with_(fuse_epilogue=False),
-                                    PALLAS.with_(fuse_epilogue=False)])
-def test_relu_conv_grads_exact_after_threading(stride, padding, policy):
-    x = _rand((2, 9, 11, 5), 13, 0.0)     # continuous pre-activation
-    w = _rand((3, 3, 5, 7), 14, 0.0)
+@pytest.mark.parametrize(
+    "policy,c,block",
+    [(p, 5, None) for p in [PALLAS, PALLAS_U,
+                            PALLAS_U.with_(fuse_epilogue=False),
+                            PALLAS.with_(fuse_epilogue=False)]]
+    + [(p, c, block) for c, block in WIDE_CHANNELS for p in [PALLAS, PALLAS_U]])
+def test_relu_conv_grads_exact_after_threading(stride, padding, policy, c,
+                                               block):
+    if block is not None:
+        policy = policy.with_(block=block)
+    x = _rand((2, 9, 11, c), 13, 0.0)     # continuous pre-activation
+    w = _rand((3, 3, c, 7), 14, 0.0)
 
     def dense(x, w):
         return jax.lax.conv_general_dilated(
@@ -152,8 +229,9 @@ def test_relu_conv_grads_exact_after_threading(stride, padding, policy):
 
     f = lambda x, w: (relu_conv(x, w, stride, padding, policy) ** 2).sum()
     g = lambda x, w: (dense(x, w) ** 2).sum()
-    np.testing.assert_allclose(f(x, w), g(x, w), rtol=1e-4)
-    ga, gb = jax.grad(f, (0, 1))(x, w), jax.grad(g, (0, 1))(x, w)
+    (fa, ga), (fb, gb) = (jax.jit(jax.value_and_grad(h, (0, 1)))(x, w)
+                          for h in (f, g))
+    np.testing.assert_allclose(fa, fb, rtol=1e-4)
     for a, b in zip(ga, gb):
         np.testing.assert_allclose(a, b, rtol=3e-4, atol=3e-4)
 

@@ -54,12 +54,19 @@ def test_relu2_matmul_vjp_exact(policy):
         np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)
 
 
+# (channels, block): C = 5 on square tiles; C = 3 and 64 give a patch K
+# (27, 576) that is not a whole number of bk = 128 tiles, C = 128 one that is.
+CONV_CHANNELS = [(5, (16, 16, 16)), (3, (16, 128, 16)), (64, (16, 128, 16)),
+                 (128, (16, 128, 16))]
+
+
 @pytest.mark.parametrize("stride,padding", [(1, "SAME"), (2, "SAME"),
                                             (1, "VALID"), (2, "VALID")])
-def test_relu_conv_vjp_exact(stride, padding):
-    policy = pol.IN_OUT.with_(kernel_impl="pallas", block=(16, 16, 16))
-    x = _rand((2, 9, 11, 5), 6)
-    w = _rand((3, 3, 5, 7), 7)
+@pytest.mark.parametrize("c,block", CONV_CHANNELS)
+def test_relu_conv_vjp_exact(stride, padding, c, block):
+    policy = pol.IN_OUT.with_(kernel_impl="pallas", block=block)
+    x = _rand((2, 9, 11, c), 6)
+    w = _rand((3, 3, c, 7), 7)
 
     def dense(x, w):
         return jax.lax.conv_general_dilated(
