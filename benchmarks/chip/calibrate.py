@@ -11,10 +11,13 @@ readings); the same numbers against the reference at HIGHEST precision are
 printed beside them for the record.  For the control seeds also: the
 reference computed in bfloat16 in the program's place (the control), and
 the reference with half of every batch left out, the mean taken over the
-rest (a planted fault).  A step that returns its state unchanged reads 1
-on both leaf numbers by construction and needs no run.  Prints one JSON
-line per seed and a summary with each number's lower reading (largest
-over the seeds) and upper readings (smallest over the control seeds).
+rest (a planted fault); on several chips also the reference over the
+first chip's images alone, which is what that chip applies when the
+gradient is not all-reduced (a planted fault).  A step that returns its
+state unchanged reads 1 on both leaf numbers by construction and needs no
+run.  Prints one JSON line per seed and a summary with each number's lower
+reading (largest over the seeds), upper readings (smallest over the
+control seeds) and the program's trace-time counters of its collectives.
 """
 import argparse
 import json
@@ -35,14 +38,17 @@ def seed_list(text: str):
 
 
 def first_grad(cell, params0, batch, dtype=None):
-    """The reference's gradient on one batch, as host arrays."""
+    """The reference's gradient on one batch, over the chunks the chips
+    see, as host arrays."""
     import functools
 
     import jax
+    from chipbench import check
     ref = cell.reference()
     kw = {} if dtype is None else {"dtype": dtype}
-    g = jax.jit(jax.grad(functools.partial(ref.loss, config=cell.config,
-                                           **kw)))(params0, *batch)
+    _, g = check.chunked_value_and_grad(
+        functools.partial(ref.loss, config=cell.config, **kw),
+        cell.config["batch_per_chip"])(params0, *batch)
     return jax.device_get(g)
 
 
@@ -76,7 +82,7 @@ def main() -> int:
     if jax.default_backend() != "tpu":
         print("calibrate.py: needs a TPU", file=sys.stderr)
         return 2
-    from chipbench import check, harness, spec
+    from chipbench import check, harness, program, spec
     from repro.launch.cache import use_compile_cache
     use_compile_cache()
     cell = spec.load_cell(args.workload)
@@ -111,14 +117,21 @@ def main() -> int:
                     for x, y in first]
             row["fault_half_batch"] = check.compare(
                 harness.run_reference(cell, params0, half), ref)
+            if cell.chips > 1:
+                per_chip = cell.config["batch_per_chip"]
+                own = [(x[:per_chip], y[:per_chip]) for x, y in first]
+                row["fault_no_allreduce"] = check.compare(
+                    harness.run_reference(cell, params0, own), ref)
         row["seconds"] = time.perf_counter() - t
         rows.append(row)
         print(json.dumps(row), flush=True)
 
-    summary = {"workload": args.workload, "lower": {}, "upper": {}}
+    summary = {"workload": args.workload, "lower": {}, "upper": {},
+               "collectives": program.collective_counts()}
     for k in check.NUMBERS:
         summary["lower"][k] = max(r["program"][k] for r in rows)
-        for kind in ("control_bf16", "fault_half_batch"):
+        for kind in ("control_bf16", "fault_half_batch",
+                     "fault_no_allreduce"):
             vals = [r[kind][k] for r in rows if kind in r]
             if vals:
                 summary["upper"].setdefault(k, {})[kind] = min(vals)

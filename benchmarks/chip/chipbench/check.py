@@ -3,8 +3,12 @@
 Set-up drives the compiled step through its first three steps from the
 seed's weights, on pool batches 0, 1 and 2.  The plain reference
 (``reference/<name>.py``, float32 at the matmul precision the config
-states) follows the same three steps from the same weights.  Five numbers
-are compared, each with a limit of its own (``limits/<workload>.json``):
+states) follows the same three steps from the same weights.  As the
+program splits a batch over its chips, each reference step takes its loss
+and gradient as the mean over chunks of ``batch_per_chip`` images, each
+chunk computed alone: with one chip the whole batch, and in a model with
+BatchNorm the statistics of each chip's own images.  Five numbers are
+compared, each with a limit of its own (``limits/<workload>.json``):
 
   loss_step1..3  |program loss - reference loss| / |reference loss| at
                  each of the three steps;
@@ -45,14 +49,35 @@ def states_readings(p0, p1, p3, losses: Sequence[float], lr: float) -> dict:
             "update": {k: float(np.linalg.norm(f3[k] - f0[k])) for k in f0}}
 
 
-def reference_readings(loss_fn: Callable, params0, batches: List,
-                       lr: float) -> dict:
-    """Three SGD steps of ``loss_fn(params, images, labels)`` from
-    ``params0`` over ``batches`` (host or device arrays)."""
+def chunked_value_and_grad(loss_fn: Callable, rows: int) -> Callable:
+    """``f(params, images, labels) -> (loss, grads)``: the mean over
+    consecutive chunks of ``rows`` images (the whole batch where it has no
+    more) of ``value_and_grad(loss_fn)``, one jitted call per chunk."""
     vg = jax.jit(jax.value_and_grad(loss_fn))
+
+    def f(params, images, labels):
+        n = images.shape[0]
+        if n <= rows:
+            return vg(params, jnp.asarray(images), jnp.asarray(labels))
+        if n % rows:
+            raise ValueError(f"a batch of {n} does not split into chunks "
+                             f"of {rows}")
+        parts = [vg(params, jnp.asarray(images[i:i + rows]),
+                    jnp.asarray(labels[i:i + rows]))
+                 for i in range(0, n, rows)]
+        return jax.tree.map(lambda *xs: sum(xs) / len(xs), *parts)
+    return f
+
+
+def reference_readings(loss_fn: Callable, params0, batches: List,
+                       lr: float, rows: int) -> dict:
+    """Three SGD steps of ``loss_fn(params, images, labels)`` from
+    ``params0`` over ``batches`` (host or device arrays), each step's
+    loss and gradient the mean over chunks of ``rows`` images."""
+    vg = chunked_value_and_grad(loss_fn, rows)
     p, losses, first = params0, [], None
     for images, labels in batches[:STEPS]:
-        loss, grads = vg(p, jnp.asarray(images), jnp.asarray(labels))
+        loss, grads = vg(p, images, labels)
         if first is None:
             first = grads
         p = jax.tree.map(lambda a, g: a - lr * g, p, grads)

@@ -3,7 +3,10 @@
 Set-up makes the weights and a pool of batches on the device from the
 seed, compiles the cell's one step shape, and drives the compiled step
 through its first three steps, each fenced with ``block_until_ready``,
-which the check compares.  The window then calls the same compiled step
+which the check compares.  On one chip the weights and the pool live on
+that chip; on several, the weights are replicated and every batch is split
+over them on its leading axis (``placement``), and the step is the
+program's data-parallel one.  The window then calls the same compiled step
 on the pool's next batches, keeping ``AHEAD_S`` seconds of steps queued on
 the device so that a host that stands still for a moment does not leave
 the chip idle, for the given seconds (``--trace 0``) or for
@@ -23,6 +26,7 @@ from typing import Callable, Dict, Optional, Sequence
 
 import jax
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from chipbench import check, flops, imagegen, layers, program, spec, tracing
 
@@ -47,6 +51,19 @@ def _compiled_bytes(compiled) -> int:
             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
 
 
+def placement(devices: Sequence):
+    """(mesh, the weights' sharding, the pool batches' sharding) for a
+    cell's devices: on one chip no mesh, and both on that chip; on several
+    a ``("data",)`` mesh over them, the weights replicated and each batch
+    split on its leading (batch) axis."""
+    if len(devices) == 1:
+        one = jax.sharding.SingleDeviceSharding(devices[0])
+        return None, one, one
+    mesh = jax.make_mesh((len(devices),), ("data",), devices=devices)
+    return (mesh, jax.sharding.NamedSharding(mesh, P()),
+            jax.sharding.NamedSharding(mesh, P("data")))
+
+
 class Setup:
     """The compiled step, its state after the first three steps, and the
     pool, built from the seed."""
@@ -56,10 +73,10 @@ class Setup:
         self.cell, self.devices = cell, list(devices)
         cfg = cell.config
         ref = cell.reference()
-        on_chip = jax.sharding.SingleDeviceSharding(self.devices[0])
+        mesh, weights, split = placement(self.devices)
         key = imagegen.seed_key(seed)
         self.params0 = jax.jit(functools.partial(ref.init_params, cfg),
-                               out_shardings=on_chip)(
+                               out_shardings=weights)(
                                    jax.random.fold_in(key, 0))
         one = imagegen.batch_fn(
             cell.traffic, batch=cell.global_batch,
@@ -69,11 +86,11 @@ class Setup:
         pool_key = jax.random.fold_in(key, 1)
         self.batches = jax.jit(
             lambda k: [one(jax.random.fold_in(k, i)) for i in range(n)],
-            out_shardings=on_chip)(pool_key)
+            out_shardings=split)(pool_key)
         jax.block_until_ready((self.params0, self.batches))
 
         t = time.perf_counter()
-        self.compiled = compiled or build_step(cfg).lower(
+        self.compiled = compiled or build_step(cfg, mesh).lower(
             self.params0, *self.batches[0]).compile()
         self.compile_s = time.perf_counter() - t
         self.hbm_bytes = _compiled_bytes(self.compiled)
@@ -168,14 +185,17 @@ def zero_tiles(cell: spec.Cell, params, batch) -> Dict[str, float]:
 def run_reference(cell: spec.Cell, params0, batches, dtype=None,
                   precision=None) -> dict:
     """The reference's readings over ``batches`` from ``params0``: float32
-    at the config's precision, or ``dtype`` / ``precision`` in its place."""
+    at the config's precision, or ``dtype`` / ``precision`` in its place;
+    each step over chunks of ``batch_per_chip`` images, as the program's
+    chips see them."""
     ref = cell.reference()
     kw = {} if dtype is None else {"dtype": dtype}
     loss_fn = functools.partial(ref.loss, config=cell.config,
                                 precision=precision, **kw)
     with jax.default_device(jax.devices()[0]):
         return check.reference_readings(loss_fn, params0, batches,
-                                        cell.config["lr"])
+                                        cell.config["lr"],
+                                        cell.config["batch_per_chip"])
 
 
 class MissingMetric(RuntimeError):

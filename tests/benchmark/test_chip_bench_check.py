@@ -19,6 +19,7 @@ sys.path.insert(0, os.path.join(ROOT, "benchmarks", "chip"))
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 from chipbench import check, harness, program, spec  # noqa: E402
+import chip_bench_faults as faults  # noqa: E402
 from chip_bench_tiny import tiny_cell  # noqa: E402
 
 WORKLOADS = [w["name"] for w in spec.load_benchmark(ROOT)["workloads"]
@@ -28,23 +29,6 @@ WORKLOADS = [w["name"] for w in spec.load_benchmark(ROOT)["workloads"]
 def _run(cell, build_step=program.build_step):
     return harness.run(cell, 2**31 + 5, 0.2, False, jax.devices("cpu"),
                        time.perf_counter(), build_step)
-
-
-def _half_batch(config):
-    """The program's step on the first half of the batch only."""
-    step = program.build_step(config)
-    return jax.jit(
-        lambda p, x, y: step(p, x[:x.shape[0] // 2], y[:y.shape[0] // 2]))
-
-
-def _unchanged_state(config):
-    """The program's step returning the state it was given."""
-    step = program.build_step(config)
-
-    def broken(p, x, y):
-        _, loss = step(p, x, y)
-        return p, loss
-    return jax.jit(broken)
 
 
 @pytest.mark.parametrize("workload", WORKLOADS)
@@ -57,7 +41,7 @@ def test_sound_program_is_correct(workload):
     assert set(check.NUMBERS) <= set(out["checks"])
 
 
-@pytest.mark.parametrize("fault", [_half_batch, _unchanged_state],
+@pytest.mark.parametrize("fault", [faults.half_batch, faults.unchanged_state],
                          ids=["half_batch", "unchanged_state"])
 @pytest.mark.parametrize("workload", WORKLOADS)
 def test_broken_step_is_not_correct(workload, fault):
@@ -68,16 +52,30 @@ def test_broken_step_is_not_correct(workload, fault):
 @pytest.mark.parametrize("workload", WORKLOADS)
 def test_bfloat16_control_is_not_correct(workload):
     cell = tiny_cell(workload)
-    ref = cell.reference()
-    key = jax.random.key(3)
-    params0 = ref.init_params(cell.config, key)
-    f = harness.imagegen.batch_fn(
-        cell.traffic, batch=cell.global_batch,
-        image_size=cell.config["image_size"],
-        channels=cell.config["channels"],
-        num_classes=cell.config["num_classes"])
-    batches = [f(jax.random.fold_in(key, i)) for i in range(check.STEPS)]
-    want = harness.run_reference(cell, params0, batches)
-    got = harness.run_reference(cell, params0, batches, jnp.bfloat16)
-    numbers = check.compare(got, want)
+    numbers = faults.bfloat16_control(cell)
     assert not check.verdict(numbers, cell.limits), numbers
+
+
+@pytest.mark.parametrize("workload", WORKLOADS)
+def test_one_chip_step_lowers_as_before(workload):
+    """On one chip the step is what it was before the benchmark took
+    several: ``jax.value_and_grad`` of the model's loss and SGD, in one
+    ``jax.jit``, with no mesh and no collective."""
+    cell = tiny_cell(workload)
+    cfg = cell.config
+    model, policy = program.model_and_policy(cfg)
+
+    def step(params, images, labels):
+        loss, grads = jax.value_and_grad(
+            lambda p: model.loss(p, images, labels, policy))(params)
+        return program.sgd(params, grads, cfg["lr"]), loss
+
+    params = jax.eval_shape(lambda k: cell.reference().init_params(cfg, k),
+                            jax.random.key(0))
+    n, s = cell.global_batch, cfg["image_size"]
+    args = (params,
+            jax.ShapeDtypeStruct((n, s, s, cfg["channels"]), jnp.float32),
+            jax.ShapeDtypeStruct((n,), jnp.int32))
+    got = program.build_step(cfg).lower(*args).as_text()
+    assert got == jax.jit(step).lower(*args).as_text()
+    assert "all_reduce" not in got and "all-reduce" not in got

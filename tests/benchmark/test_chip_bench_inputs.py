@@ -71,6 +71,20 @@ def test_content_boxes_at_224():
         (0, 0, 224, 224)]
 
 
+def test_letterbox_sharded_draws_the_letterbox_frames():
+    """The four-chip cell's mix differs from ``letterbox`` only in its
+    name and why, so a seed draws the same pool for both."""
+    drawn = ("aspect_ratios", "noise_std", "pool_batches")
+    sharded, plain = _traffic("letterbox_sharded"), _traffic("letterbox")
+    assert set(sharded) == set(plain)
+    assert {k: sharded[k] for k in drawn} == {k: plain[k] for k in drawn}
+    key = imagegen.seed_key(2**31 + 777)
+    a, b = (imagegen.batch_fn(t, batch=4, image_size=32, channels=3,
+                              num_classes=10)(key) for t in (sharded, plain))
+    for x, y in zip(a, b):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
 def test_fullframe_fills_the_frame():
     f = imagegen.batch_fn(_traffic("fullframe"), batch=4, image_size=32,
                           channels=3, num_classes=10)
@@ -129,6 +143,8 @@ def test_benchmark_names_and_units():
     for c in b["configs"]:
         assert all(name.match(k) for k in c["reduced"])
         assert os.path.isfile(os.path.join(ROOT, c["file"]))
+    pairs = [(w["config"], w["traffic"]) for w in b["workloads"]]
+    assert len(pairs) == len(set(pairs))
     for w in b["workloads"]:
         assert name.match(w["config"]) and name.match(w["traffic"])
         assert os.path.isfile(os.path.join(BENCH, "traffic",
